@@ -113,6 +113,7 @@ from repro.core import rows as result_rows
 from repro.core import runners as runner_registry
 from repro.core.protocol import run_scenarios_seeds
 from repro.engine import session_cache_stats, session_cache_stats_by_domain
+from repro.launch.compile_cache import enable_compile_cache
 
 BASELINE_PATH = os.path.join(os.path.dirname(__file__), "frontier_baseline.json")
 
@@ -144,7 +145,7 @@ def _aggregate_row(seed_rows) -> dict:
     return row
 
 
-def _runner_cfgs(spec, methods=METHODS, devices=None,
+def runner_cfgs(spec, methods=METHODS, devices=None,
                  use_kernels: bool = False) -> dict:
     """Resolve every method through THE runner registry
     (``repro.core.runners``): the entry supplies the runner callable, its
@@ -190,8 +191,8 @@ def run_scenario_group(bundles_per_scenario, seeds, methods=METHODS,
     """
     specs = [bs[0].spec for bs in bundles_per_scenario]
     group_size = len(specs)
-    runner_cfgs = _runner_cfgs(specs[0], methods, devices=devices,
-                               use_kernels=use_kernels)
+    cfgs = runner_cfgs(specs[0], methods, devices=devices,
+                       use_kernels=use_kernels)
     # the engine's own fast-path precondition: apply-fn identity + equal
     # SSL configs + equal per-party feature shapes. Heterogeneous feature
     # blocks (e.g. credit/feature-skew) — or equal-dim parties with
@@ -211,7 +212,7 @@ def run_scenario_group(bundles_per_scenario, seeds, methods=METHODS,
         fault_kw["faults"] = [[spec.fault for _ in seeds] for spec in specs]
     rows = []
     for method in methods:
-        runner, cfg = runner_cfgs[method]
+        runner, cfg = cfgs[method]
         t0 = time.time()
         misses0 = session_cache_stats()["misses"]
         results = run_scenarios_seeds(
@@ -618,6 +619,7 @@ def main(argv=None) -> int:
         "via --xla_force_host_platform_device_count before jax initializes",
     )
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.devices is not None and args.devices > 1:
         # set XLA_FLAGS BEFORE the first backend touch (any device_count()

@@ -40,6 +40,7 @@ from repro.core.protocol import run_one_shot
 from repro.checkpoint import load_artifact, save_artifact
 from repro.engine import session_cache_stats
 from repro.launch import vfl_serve
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.vfl_serve import ServingEngine
 
 BASELINE_PATH = os.path.join(os.path.dirname(__file__),
@@ -166,6 +167,7 @@ def main(argv=None) -> int:
     ap.add_argument("--check-gate", action="store_true")
     ap.add_argument("--baseline", default=BASELINE_PATH)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     t0 = time.time()
     if args.train:

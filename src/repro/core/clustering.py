@@ -20,11 +20,18 @@ import jax
 import jax.numpy as jnp
 
 
+# k-means matmuls are f32-exact: at a TPU's default precision an f32 matmul
+# is one bf16 pass, which flips assignments of near-tied gradient rows. The
+# Pallas assignment kernel runs its dot at the same precision, so the two
+# routes stay bit-equal on the chip (kernels/kmeans/ops.py)
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
 def _pairwise_sq_dists(x: jnp.ndarray, centers: jnp.ndarray) -> jnp.ndarray:
     """(N, C) squared euclidean distances, MXU-friendly expansion."""
     x2 = jnp.sum(x * x, axis=1, keepdims=True)          # (N, 1)
     c2 = jnp.sum(centers * centers, axis=1)             # (C,)
-    return x2 - 2.0 * (x @ centers.T) + c2[None, :]
+    return x2 - 2.0 * jnp.matmul(x, centers.T, precision=_HIGHEST) + c2[None, :]
 
 
 def assign_clusters(x: jnp.ndarray, centers: jnp.ndarray, use_kernel: bool = False
@@ -80,7 +87,7 @@ def _normalized_search(key, x: jnp.ndarray, num_clusters: int,
             # full-size assignment is worth a kernel launch
             assign = assign_clusters(xn, centers, use_kernel=False)
             onehot = jax.nn.one_hot(assign, num_clusters, dtype=xn.dtype)  # (N, C)
-            sums = onehot.T @ xn                                           # (C, d)
+            sums = jnp.matmul(onehot.T, xn, precision=_HIGHEST)           # (C, d)
             counts = jnp.sum(onehot, axis=0)[:, None]
             new = sums / jnp.maximum(counts, 1.0)
             # keep empty clusters where they were

@@ -24,9 +24,13 @@ def sdpa_transform(h_u_a: jnp.ndarray, h_o_a: jnp.ndarray, h_o_b: jnp.ndarray,
     if use_kernel:
         from repro.kernels.sdpa_estimator import ops as kops
         return kops.sdpa_estimate(h_u_a, h_o_a, h_o_b)
+    # f32-exact on a TPU too, where a default-precision f32 matmul is one
+    # bf16 pass — the kernel route computes at the same precision
+    hi = jax.lax.Precision.HIGHEST
     d = h_u_a.shape[-1]
-    scores = (h_u_a @ h_o_a.T) / jnp.sqrt(jnp.asarray(d, h_u_a.dtype))
-    return jax.nn.softmax(scores, axis=-1) @ h_o_b
+    scores = (jnp.matmul(h_u_a, h_o_a.T, precision=hi)
+              / jnp.sqrt(jnp.asarray(d, h_u_a.dtype)))
+    return jnp.matmul(jax.nn.softmax(scores, axis=-1), h_o_b, precision=hi)
 
 
 def sdpa_transform_batched(h_u_a: jnp.ndarray, h_o_a: jnp.ndarray,

@@ -39,7 +39,6 @@ from typing import Any, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec
 
 from repro.launch.mesh import BATCH_AXIS, make_batch_mesh
@@ -110,7 +109,8 @@ def strip_stacked(tree: Any, n: int) -> Any:
     return jax.tree_util.tree_map(lambda a: a[:n], tree)
 
 
-def shard_jit(fn, mesh: Optional[Mesh], donate_params: bool = True):
+def shard_jit(fn, mesh: Optional[Mesh], donate_params: bool = True,
+              compiler_options: Optional[dict] = None):
     """Compile a batched session over the stacked leading axis.
 
     ``mesh is None`` → the historical single-device ``jax.jit`` (stacked
@@ -120,9 +120,12 @@ def shard_jit(fn, mesh: Optional[Mesh], donate_params: bool = True):
     is exactly the single-device program restricted to each slice. Donation
     is disabled on the sharded path: inputs arrive host-committed and are
     resharded onto the mesh, so their buffers are not reusable in place.
+    ``compiler_options`` pass through to ``jax.jit`` unchanged.
     """
     if mesh is None:
-        return jax.jit(fn, donate_argnums=(0,) if donate_params else ())
+        return jax.jit(fn, donate_argnums=(0,) if donate_params else (),
+                       compiler_options=compiler_options)
     spec = PartitionSpec(BATCH_AXIS)
-    return jax.jit(shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec,
-                             check_rep=False))
+    return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=spec,
+                                 out_specs=spec, check_vma=False),
+                   compiler_options=compiler_options)

@@ -12,16 +12,13 @@ Each kernel directory has kernel.py (pl.pallas_call + BlockSpec), ops.py
 (jit'd public wrapper with padding/dtype plumbing) and ref.py (pure-jnp
 oracle used by the tests' assert_allclose sweeps).
 
-Kernels run in interpret mode on CPU (``REPRO_KERNEL_INTERPRET=1`` or
-automatically when no TPU is present); on TPU they compile natively.
+Kernels run in interpret mode exactly when no TPU is present; on a TPU
+they always compile natively, so a chip run can never interpret by
+accident.
 """
-import os
-
 import jax
 
 
 def interpret_mode() -> bool:
-    env = os.environ.get("REPRO_KERNEL_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
+    """True iff the default backend is not a TPU (CPU tests, rehearsals)."""
     return jax.default_backend() != "tpu"

@@ -2,7 +2,7 @@
 
 Tiling: the grid walks row-blocks of x; each program instance loads an
 (BN, d) tile of points and the full (C, d) center matrix into VMEM (C is the
-class count — ≤ a few hundred — so centers always fit), forms the distance
+class count — ≤ a few hundred — so centers fit), forms the distance
 tile with one MXU matmul (‖x‖² − 2·x·μᵀ + ‖μ‖²) and reduces the argmin across
 the padded C lanes in VREGs.
 
@@ -17,16 +17,24 @@ VMEM budget per instance (f32): BN·d + C·d + BN·C floats — the leading batc
 axis contributes nothing per program (its block width is 1).
 With BN=256, d≤4096, C≤1024: 256·4096·4 + 1024·4096·4 + 256·1024·4 ≈ 21.3 MB
 worst case — ops.py clamps BN down when d·C is large so the working set stays
-within the ~16 MB/core VMEM of TPU v5e. MXU alignment: BN multiple of 8,
-d and C padded to multiples of 128 by ops.py.
+within the default scoped VMEM limit (and raises that limit when even the
+smallest block does not fit). Alignment: BN a multiple of 128, d padded to
+a multiple of 128 and C to a multiple of 8 by ops.py.
+
+The assignments are written as a ``(B, 1, N)`` array in ``(1, 1, BN)``
+blocks: the TPU lowering wants the last two block dims divisible by
+(8, 128) or equal to the array's, which a ``(1, BN)`` block of a ``(B, N)``
+array breaks for every B ≥ 2.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kmeans_assign_kernel(x_ref, c_ref, out_ref):
@@ -34,19 +42,23 @@ def _kmeans_assign_kernel(x_ref, c_ref, out_ref):
     cen = c_ref[0].astype(jnp.float32)          # (C, d)
     x2 = jnp.sum(x * x, axis=1, keepdims=True)                   # (BN, 1)
     c2 = jnp.sum(cen * cen, axis=1)[None, :]                     # (1, C)
-    # MXU: (BN, d) @ (d, C)
+    # MXU: (BN, d) @ (d, C), f32-exact (see ops.py on precision)
     dots = jax.lax.dot_general(x, cen, (((1,), (1,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)
     dist = x2 - 2.0 * dots + c2                                  # (BN, C)
-    out_ref[0, :] = jnp.argmin(dist, axis=1).astype(jnp.int32)
+    out_ref[0, 0, :] = jnp.argmin(dist, axis=1).astype(jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("block_n", "vmem_limit", "interpret"))
 def kmeans_assign_batched_padded(x: jnp.ndarray, centers: jnp.ndarray,
-                                 block_n: int = 256, interpret: bool = False
-                                 ) -> jnp.ndarray:
+                                 block_n: int = 256,
+                                 vmem_limit: Optional[int] = None,
+                                 interpret: bool = False) -> jnp.ndarray:
     """x (B, N, d), centers (B, C, d) → (B, N) int32; N % block_n == 0,
-    d/C already padded.
+    d/C already padded. ``vmem_limit`` (bytes) overrides the compiler's
+    default scoped VMEM limit; ``None`` keeps the default.
 
     Padded center rows must be filled with +inf-distance sentinels by ops.py
     (i.e. rows of large magnitude) so they never win the argmin.
@@ -55,17 +67,21 @@ def kmeans_assign_batched_padded(x: jnp.ndarray, centers: jnp.ndarray,
     _, c, _ = centers.shape
     assert n % block_n == 0, (n, block_n)
     grid = (b, n // block_n)
-    return pl.pallas_call(
+    params = (None if vmem_limit is None
+              else pltpu.CompilerParams(vmem_limit_bytes=vmem_limit))
+    out = pl.pallas_call(
         _kmeans_assign_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_n, d), lambda bi, i: (bi, i, 0)),
             pl.BlockSpec((1, c, d), lambda bi, i: (bi, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_n), lambda bi, i: (bi, i)),
-        out_shape=jax.ShapeDtypeStruct((b, n), jnp.int32),
+        out_specs=pl.BlockSpec((1, 1, block_n), lambda bi, i: (bi, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((b, 1, n), jnp.int32),
+        compiler_params=params,
         interpret=interpret,
     )(x, centers)
+    return out[:, 0, :]
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))

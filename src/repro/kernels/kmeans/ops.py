@@ -27,16 +27,30 @@ never leaves VMEM; the roofline note above governs when to flip
 
 VMEM budget per grid instance (f32), mirroring kmeans/kernel.py — the
 leading batch axis has block width 1 and adds NOTHING per instance, so
-block sizing is batch-independent:
+block sizing is batch-independent. The pipeline double-buffers both input
+tiles:
 
   tile              shape        bytes (BN=256, d=4096, C=1024 worst case)
-  x row-tile        (BN, d)      256·4096·4 ≈ 4.2 MB
-  centers           (C,  d)      1024·4096·4 ≈ 16.8 MB
+  x row-tile        (BN, d)      256·4096·4 ≈ 4.2 MB   (×2 buffers)
+  centers           (C,  d)      1024·4096·4 ≈ 16.8 MB (×2 buffers)
   distance tile     (BN, C)      256·1024·4 ≈ 1.0 MB
 
-``_pick_block_n`` clamps BN down until the working set fits the
-``_VMEM_BUDGET`` (12 MB, headroom under the ~16 MB/core of TPU v5e).
-MXU alignment: BN multiple of 8; d and C padded to multiples of 128.
+``_pick_block_n`` takes the largest BN in (512, 256, 128) whose working set
+fits ``_VMEM_BUDGET`` (12 MB, headroom under the 16 MB default scoped VMEM
+limit of TPU v5e) and never a block wider than the padded row count. When
+even BN=128 does not fit — the worst case above — the plan raises the
+kernel's scoped VMEM limit to the working set plus headroom instead (v5e
+has 128 MiB of VMEM per core). BN stays a multiple of 128 because it is
+the lane dim of the output block; d is padded to 128 lanes, C to 8
+sublanes.
+
+Precision: the kernel's dot and the jnp oracles (``ref.py``,
+``core/clustering.py``) all run at ``Precision.HIGHEST``. At a TPU's
+default precision an f32 matmul — XLA's and the Pallas kernel's alike — is
+one bf16 pass: on a v5e both sides then flipped 64 of 32768 assignments of
+unit rows against a float64 reference, and the two disagreed with each
+other wherever their roundings differed. At HIGHEST both match float64, so
+the kernel stays bit-equal to the oracle on the chip as in interpret mode.
 """
 from __future__ import annotations
 
@@ -48,7 +62,7 @@ from repro.kernels.kmeans.kernel import (kmeans_assign_batched_padded,
 
 _LANE = 128     # MXU/VREG lane width
 _SUBLANE = 8
-_VMEM_BUDGET = 12 * 2**20   # leave headroom under ~16 MB/core
+_VMEM_BUDGET = 12 * 2**20   # headroom under the 16 MB default scoped limit
 
 assert kmeans_assign_padded is not None  # width-1 entry, re-exported
 
@@ -57,20 +71,32 @@ def _round_up(v: int, m: int) -> int:
     return (v + m - 1) // m * m
 
 
-def _pick_block_n(d_pad: int, c_pad: int) -> int:
-    for bn in (512, 256, 128, 64, 32, 16, 8):
-        vmem = 4 * (bn * d_pad + c_pad * d_pad + 2 * bn * c_pad)
-        if vmem <= _VMEM_BUDGET:
+def _vmem_bytes(bn: int, d_pad: int, c_pad: int) -> int:
+    """Working set of one grid instance: double-buffered x/centers tiles,
+    the distance tile and the output row, f32."""
+    return 4 * (2 * (bn * d_pad + c_pad * d_pad) + bn * c_pad + 2 * bn)
+
+
+def _pick_block_n(n: int, d_pad: int, c_pad: int) -> int:
+    cap = _round_up(max(n, 1), _LANE)
+    for bn in (512, 256, 128):
+        if bn <= cap and _vmem_bytes(bn, d_pad, c_pad) <= _VMEM_BUDGET:
             return bn
-    return 8
+    return _LANE
 
 
 def _pad_plan(n: int, d: int, c: int):
+    """(n_pad, d_pad, c_pad, block_n, vmem_limit) for one assignment call;
+    ``vmem_limit`` is None unless the working set needs more than the
+    default scoped VMEM."""
     d_pad = _round_up(max(d, _LANE), _LANE)
     c_pad = _round_up(max(c, _SUBLANE), _SUBLANE)
-    bn = _pick_block_n(d_pad, c_pad)
+    bn = _pick_block_n(n, d_pad, c_pad)
     n_pad = _round_up(max(n, bn), bn)
-    return n_pad, d_pad, c_pad, bn
+    need = _vmem_bytes(bn, d_pad, c_pad)
+    vmem_limit = (None if need <= _VMEM_BUDGET
+                  else _round_up(need + need // 4, 2**20))
+    return n_pad, d_pad, c_pad, bn, vmem_limit
 
 
 def kmeans_assign_batched(x: jnp.ndarray, centers: jnp.ndarray) -> jnp.ndarray:
@@ -80,7 +106,7 @@ def kmeans_assign_batched(x: jnp.ndarray, centers: jnp.ndarray) -> jnp.ndarray:
     axis is the stacked fold axis (seeds × scenarios × parties upstream)."""
     b, n, d = x.shape
     c = centers.shape[1]
-    n_pad, d_pad, c_pad, bn = _pad_plan(n, d, c)
+    n_pad, d_pad, c_pad, bn, vmem_limit = _pad_plan(n, d, c)
 
     xp = jnp.zeros((b, n_pad, d_pad), jnp.float32
                    ).at[:, :n, :d].set(x.astype(jnp.float32))
@@ -91,6 +117,7 @@ def kmeans_assign_batched(x: jnp.ndarray, centers: jnp.ndarray) -> jnp.ndarray:
         cp = cp.at[:, c:, 0].set(3e18)
 
     out = kmeans_assign_batched_padded(xp, cp, block_n=bn,
+                                       vmem_limit=vmem_limit,
                                        interpret=interpret_mode())
     return out[:, :n]
 

@@ -1,7 +1,13 @@
-"""Pure-jnp oracle for the k-means assignment kernel."""
+"""Pure-jnp oracle for the k-means assignment kernel.
+
+Matmuls run at ``Precision.HIGHEST``: at a TPU's default precision an f32
+matmul is one bf16 pass, which flips assignments near ties."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
+
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def kmeans_assign(x: jnp.ndarray, centers: jnp.ndarray) -> jnp.ndarray:
@@ -13,7 +19,7 @@ def kmeans_assign(x: jnp.ndarray, centers: jnp.ndarray) -> jnp.ndarray:
     centers = centers.astype(jnp.float32)
     x2 = jnp.sum(x * x, axis=1, keepdims=True)
     c2 = jnp.sum(centers * centers, axis=1)
-    d = x2 - 2.0 * (x @ centers.T) + c2[None, :]
+    d = x2 - 2.0 * jnp.matmul(x, centers.T, precision=_HIGHEST) + c2[None, :]
     return jnp.argmin(d, axis=1).astype(jnp.int32)
 
 
@@ -22,5 +28,5 @@ def kmeans_min_dist(x: jnp.ndarray, centers: jnp.ndarray) -> jnp.ndarray:
     centers = centers.astype(jnp.float32)
     x2 = jnp.sum(x * x, axis=1, keepdims=True)
     c2 = jnp.sum(centers * centers, axis=1)
-    d = x2 - 2.0 * (x @ centers.T) + c2[None, :]
+    d = x2 - 2.0 * jnp.matmul(x, centers.T, precision=_HIGHEST) + c2[None, :]
     return jnp.min(d, axis=1)

@@ -53,6 +53,7 @@ def _sdpa_kernel(no_valid: int,
     q = q_ref[0].astype(jnp.float32)                    # (BU, d)
     k = k_ref[0].astype(jnp.float32)                    # (BO, d)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)  # (BU, BO)
     col = j * bo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(col < no_valid, s, _NEG_INF)
@@ -65,6 +66,7 @@ def _sdpa_kernel(no_valid: int,
     l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
     pv = jax.lax.dot_general(p, v_ref[0].astype(jnp.float32),
                              (((1,), (0,)), ((), ())),
+                             precision=jax.lax.Precision.HIGHEST,
                              preferred_element_type=jnp.float32)  # (BU, db)
     acc_ref[...] = acc_ref[...] * alpha + pv
     m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)

@@ -39,6 +39,13 @@ width 1, so per-instance VMEM is identical to the unbatched grid and
 ``_pick_blocks`` shrinks BU=BO from 512 down until the sum fits the 12 MB
 ``_VMEM_BUDGET`` (headroom under ~16 MB/core). Blocks are MXU-aligned
 multiples of (8, 128); d and d_b are padded to 128 lanes.
+
+Precision: both kernel dots and the jnp oracles (``ref.py``,
+``core/estimator.py``) run at ``Precision.HIGHEST``. At a TPU's default
+precision each f32 matmul is one bf16 pass: on a v5e the kernel was then
+8e-3 and the oracle 1e-2 off a float64 reference, and 5e-3 apart. At
+HIGHEST both are within ~1.3e-6 of float64, and the 1e-5 parity holds on
+the chip as in interpret mode.
 """
 from __future__ import annotations
 

@@ -1,4 +1,7 @@
-"""Pure-jnp oracle for the SDPA representation-estimation kernel (Eq. 10)."""
+"""Pure-jnp oracle for the SDPA representation-estimation kernel (Eq. 10).
+
+Matmuls run at ``Precision.HIGHEST`` (a TPU's default f32 matmul is one
+bf16 pass, ~1e-2 off)."""
 from __future__ import annotations
 
 import jax
@@ -15,5 +18,7 @@ def sdpa_estimate(h_u: jnp.ndarray, h_o_a: jnp.ndarray, h_o_b: jnp.ndarray
     h_o_a = h_o_a.astype(jnp.float32)
     h_o_b = h_o_b.astype(jnp.float32)
     d = h_u.shape[-1]
-    scores = (h_u @ h_o_a.T) / jnp.sqrt(jnp.asarray(d, jnp.float32))
-    return jax.nn.softmax(scores, axis=-1) @ h_o_b
+    hi = jax.lax.Precision.HIGHEST
+    scores = (jnp.matmul(h_u, h_o_a.T, precision=hi)
+              / jnp.sqrt(jnp.asarray(d, jnp.float32)))
+    return jnp.matmul(jax.nn.softmax(scores, axis=-1), h_o_b, precision=hi)

@@ -30,14 +30,18 @@ def forced_host_devices(count: int) -> None:
 
 def make_batch_mesh(num_devices: int | None = None):
     """1-D mesh over the engine's anonymous stacked batch axis (DESIGN.md
-    §14). ``None`` takes every visible device."""
+    §14). ``None`` takes every visible device. The axis is ``Auto``: the
+    sessions shard it inside ``shard_map`` and hand back ordinary arrays,
+    which callers index per entry (an ``Explicit`` axis, ``make_mesh``'s
+    default, would make every such index a sharding-typed gather)."""
     n = jax.device_count() if num_devices is None else int(num_devices)
     if n > jax.device_count():
         raise ValueError(
             f"requested a {n}-device batch mesh but only "
             f"{jax.device_count()} device(s) are visible — on CPU, call "
             "repro.launch.mesh.forced_host_devices before jax initializes")
-    return jax.make_mesh((n,), (BATCH_AXIS,))
+    return jax.make_mesh((n,), (BATCH_AXIS,),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -10,7 +10,7 @@ stacked extractor call, the serving analogue of the engine's training-time
 fast path) and Python-composed inside the same jit otherwise — and drives
 continuous traffic through the fixed-shape masked batcher of
 ``launch/batching.py``: requests pad to the engine's capacity, validity
-masks neutralize the padding, and input buffers are donated (off-CPU), so
+masks neutralize the padding, and input buffers are donated, so
 changing traffic never recompiles and steady-state serving allocates no
 fresh forward buffers.
 
@@ -50,6 +50,7 @@ from repro.engine.dispatch import estimate_missing_fused
 from repro.engine.sessions import cached_session, model_key
 from repro.kernels import interpret_mode
 from repro.launch import batching
+from repro.launch.compile_cache import enable_compile_cache
 
 SERVING_DOMAIN = "serving"
 
@@ -107,11 +108,11 @@ def _serving_key(art: TrainedVFLModel) -> tuple:
             art.parties_are_homogeneous)
 
 
-def _build_fused_forward(art: TrainedVFLModel, donate: bool):
+def _build_fused_forward(art: TrainedVFLModel):
     """ONE jitted program: K extractors + joint head. Parameters travel as
     arguments (the session-cache contract), the per-party inputs are donated
-    off-CPU (they are per-request scratch), and the validity mask zeroes
-    padding logits."""
+    (they are per-request scratch), and the validity mask zeroes padding
+    logits."""
     exts = art.extractors()
     clf = art.classifier()
 
@@ -135,9 +136,8 @@ def _build_fused_forward(art: TrainedVFLModel, donate: bool):
             return jnp.where(mask[:, None], logits, 0.0)
 
     # donating params would free them after the first call; only the
-    # per-request inputs (xs, mask) are scratch. CPU donation is a no-op
-    # that warns, so gate on backend.
-    return jax.jit(raw, donate_argnums=(2, 3) if donate else ())
+    # per-request inputs (xs, mask) are scratch
+    return jax.jit(raw, donate_argnums=(2, 3))
 
 
 class ServingEngine:
@@ -150,7 +150,6 @@ class ServingEngine:
         self.art = art
         self.capacity = int(capacity)
         self.router = router or KernelRouter.default()
-        self._donate = jax.default_backend() != "cpu"
         if art.parties_are_homogeneous:
             self._ext_params = jax.tree_util.tree_map(
                 lambda *ps: jnp.stack(ps),
@@ -162,9 +161,8 @@ class ServingEngine:
     def _fused(self):
         """The session-cached jitted forward (hits/misses visible under
         ``session_cache_stats("serving")``)."""
-        donate = self._donate
         return cached_session(SERVING_DOMAIN, _serving_key(self.art),
-                              lambda: _build_fused_forward(self.art, donate))
+                              lambda: _build_fused_forward(self.art))
 
     def step(self, batch: batching.MaskedBatch) -> jnp.ndarray:
         """One fixed-shape forward over a padded batch → (capacity, C)
@@ -258,6 +256,7 @@ def main(argv=None) -> int:
                     help="rows per request (default: capacity)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     t0 = time.time()
     art = load_artifact(args.artifact)

@@ -31,7 +31,6 @@ from typing import Callable, Dict
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import clustering
 from repro.core.ssl import SSLConfig, cross_entropy
@@ -63,10 +62,10 @@ def make_vanilla_vfl_step(mesh: Mesh, feat_dim: int, hidden: int, rep_dim: int,
     ext = _make_extractor(feat_dim, hidden, rep_dim)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P("pod"), P("pod", "data"), P("data"), P(None, None)),
         out_specs=(P("pod"), P()),
-        check_rep=False)
+        check_vma=False)
     def step(params, x, y, w_head):
         # params leaves (1, f, h) locally; x (1, b_local, f)
         wp = jax.tree_util.tree_map(lambda a: a[0], params)
@@ -109,11 +108,11 @@ def make_oneshot_vfl_session(mesh: Mesh, feat_dim: int, hidden: int,
     ssl_step = make_ssl_step_fn(ext, head, ssl_cfg, tx)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P("pod"), P("pod", "data"), P("pod", "data"),
                   P("data"), P(None, None)),
         out_specs=(P("pod"), P()),
-        check_rep=False)
+        check_vma=False)
     def session(params, x_o, x_u, y, w_head):
         wp = jax.tree_util.tree_map(lambda a: a[0], params)
         xo, xu = x_o[0], x_u[0]

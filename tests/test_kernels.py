@@ -8,10 +8,6 @@ from repro.kernels.decode_attention import ops as dec_ops, ref as dec_ref
 from repro.kernels.kmeans import ops as km_ops, ref as km_ref
 from repro.kernels.sdpa_estimator import ops as sdpa_ops, ref as sdpa_ref
 
-requires_tpu = pytest.mark.skipif(
-    jax.default_backend() != "tpu",
-    reason="compiled (non-interpret) Pallas grids need a TPU backend")
-
 
 # ----------------------------------------------------------------- kmeans --
 @pytest.mark.parametrize("n,d,c", [
@@ -151,27 +147,6 @@ def test_batched_grids_vmap_directly():
     native = km_ops.kmeans_assign_batched(x, cen)
     vmapped = jax.vmap(km_ops.kmeans_assign)(x, cen)
     assert np.array_equal(np.asarray(native), np.asarray(vmapped))
-
-
-@requires_tpu
-def test_kmeans_batched_grid_compiled_mode(monkeypatch):
-    """The same parity with interpret forced OFF — the Mosaic-compiled
-    grid, not the interpreter (TPU only)."""
-    monkeypatch.setattr(km_ops, "interpret_mode", lambda: False)
-    x, cen = _km_batch(4, 300, 64, 10)
-    got = km_ops.kmeans_assign_batched(x, cen)
-    want = jax.vmap(km_ref.kmeans_assign)(x, cen)
-    assert np.array_equal(np.asarray(got), np.asarray(want))
-
-
-@requires_tpu
-def test_sdpa_batched_grid_compiled_mode(monkeypatch):
-    monkeypatch.setattr(sdpa_ops, "interpret_mode", lambda: False)
-    hu, hoa, hob = _sdpa_batch(4, 512, 128, 64, 64)
-    got = sdpa_ops.sdpa_estimate_batched(hu, hoa, hob)
-    want = jax.vmap(sdpa_ref.sdpa_estimate)(hu, hoa, hob)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=1e-5, rtol=1e-5)
 
 
 # ------------------------------------------------------------ decode attn --
