@@ -137,7 +137,6 @@ def _aggregate_row(seed_rows) -> dict:
         metric_std=var ** 0.5,
         metric_min=min(metrics),
         metric_max=max(metrics),
-        wall_s=round(sum(r["wall_s"] for r in seed_rows), 2),
     )
     paths = {r.get("engine_path") for r in seed_rows}
     if len(paths) != 1:
@@ -213,7 +212,6 @@ def run_scenario_group(bundles_per_scenario, seeds, methods=METHODS,
     rows = []
     for method in methods:
         runner, cfg = cfgs[method]
-        t0 = time.time()
         misses0 = session_cache_stats()["misses"]
         results = run_scenarios_seeds(
             runner,
@@ -222,7 +220,6 @@ def run_scenario_group(bundles_per_scenario, seeds, methods=METHODS,
             [[b.extractors for b in bs] for bs in bundles_per_scenario],
             [[b.ssl_cfgs for b in bs] for bs in bundles_per_scenario],
             cfg, **fault_kw)
-        wall = time.time() - t0
         misses = session_cache_stats()["misses"] - misses0
         for spec, scen_results in zip(specs, results):
             seed_rows = []
@@ -234,8 +231,6 @@ def run_scenario_group(bundles_per_scenario, seeds, methods=METHODS,
                     scenario=spec.name,
                     seed=seed,
                     method=method,
-                    # whole-GROUP sweep wall, amortized per (scenario, seed)
-                    wall_s=round(wall / (len(seeds) * group_size), 2),
                     cache_misses=misses,          # whole-group fresh builds
                     group_size=group_size,        # partitioner ground truth
                     vmap_eligible=vmap_eligible,
@@ -248,7 +243,7 @@ def run_scenario_group(bundles_per_scenario, seeds, methods=METHODS,
                 print(
                     "{scenario:>18s} {method:>9s} s{seed:<2d} "
                     "{metric_name}={metric:.4f} bytes={comm_bytes:>10d} "
-                    "times={comm_times:>6d} ({wall_s:.0f}s)".format(**row),
+                    "times={comm_times:>6d}".format(**row),
                     flush=True,
                 )
             rows.extend(seed_rows)
@@ -258,8 +253,7 @@ def run_scenario_group(bundles_per_scenario, seeds, methods=METHODS,
                 print(
                     "{scenario:>18s} {method:>9s} agg "
                     "{metric_name}={metric_mean:.4f}±{metric_std:.4f} "
-                    "[{metric_min:.4f}, {metric_max:.4f}] "
-                    "({wall_s:.0f}s total)".format(**agg),
+                    "[{metric_min:.4f}, {metric_max:.4f}]".format(**agg),
                     flush=True,
                 )
     return rows

@@ -32,7 +32,7 @@ from typing import List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
-from repro import engine
+from repro import engine, obs
 from repro.core import clustering
 from repro.core.client import VFLClient, make_client, ssl_task_for
 from repro.core.comm import CommLedger, nbytes
@@ -370,170 +370,182 @@ def _one_shot_seeds(
         fkeys = [jax.random.fold_in(keys[s], _FAULT_STREAM)
                  for s in range(num_seeds)]
 
-    st_keys, k_srvs, clients_all, servers = [], [], [], []
-    for s in range(num_seeds):
-        key, k_clients, k_srv = jax.random.split(keys[s], 3)
-        given = clients_per_seed[s] if clients_per_seed is not None else None
-        clients = (given if given is not None else
-                   _build_clients(k_clients, splits[s], extractors[s],
-                                  ssl_cfgs[s]))
-        st_keys.append(key)
-        k_srvs.append(k_srv)
-        clients_all.append(clients)
-        servers.append(VFLServer(num_classes=splits[s].num_classes))
+    with obs.span("init"):
+        st_keys, k_srvs, clients_all, servers = [], [], [], []
+        for s in range(num_seeds):
+            key, k_clients, k_srv = jax.random.split(keys[s], 3)
+            given = (clients_per_seed[s] if clients_per_seed is not None
+                     else None)
+            clients = (given if given is not None else
+                       _build_clients(k_clients, splits[s], extractors[s],
+                                      ssl_cfgs[s]))
+            st_keys.append(key)
+            k_srvs.append(k_srv)
+            clients_all.append(clients)
+            servers.append(VFLServer(num_classes=splits[s].num_classes))
 
-    # ① clients upload overlap representations. A party dropped before
-    # this point never shows up: the server zero-imputes its H_o^k slot
-    # (fixed shapes — the fold never re-compiles) and no event is logged.
-    reps_all = [[c.extract(x_o).astype(cfg.rep_dtype)
-                 for c, x_o in zip(clients_all[s], splits[s].aligned)]
-                for s in range(num_seeds)]
-    if faulted:
-        for s, fa in enumerate(faults):
-            if fa is None:
-                continue
-            for k in range(num_parties):
-                if fa.drops(k, POINT_UPLOAD1):
-                    reps_all[s][k] = jnp.zeros_like(reps_all[s][k])
-                else:
-                    reps_all[s][k] = _dp_noised(fkeys[s], 1, k, fa,
-                                                reps_all[s][k])
-    r1 = _phase_round(ledger, entry_ledgers)
-    for k in range(num_parties):
-        _log_phase(ledger, entry_ledgers, k, "up", "reps_overlap",
-                   [reps_all[s][k] for s in range(num_seeds)], r1,
-                   skip=_drop_skip(faults if faulted else None, k,
-                                   POINT_UPLOAD1, num_seeds))
-    # the server's last-seen view of every party, AFTER imputation/noise —
-    # what Eq. 10 reconstruction attends over at step ⑤
-    stale_reps = ([list(reps) for reps in reps_all] if faulted else None)
+    with obs.span("p1.extract"):
+        # ① clients upload overlap representations. A party dropped before
+        # this point never shows up: the server zero-imputes its H_o^k slot
+        # (fixed shapes — the fold never re-compiles) and no event is logged.
+        reps_all = [[c.extract(x_o).astype(cfg.rep_dtype)
+                     for c, x_o in zip(clients_all[s], splits[s].aligned)]
+                    for s in range(num_seeds)]
+        if faulted:
+            for s, fa in enumerate(faults):
+                if fa is None:
+                    continue
+                for k in range(num_parties):
+                    if fa.drops(k, POINT_UPLOAD1):
+                        reps_all[s][k] = jnp.zeros_like(reps_all[s][k])
+                    else:
+                        reps_all[s][k] = _dp_noised(fkeys[s], 1, k, fa,
+                                                    reps_all[s][k])
+        r1 = _phase_round(ledger, entry_ledgers)
+        for k in range(num_parties):
+            _log_phase(ledger, entry_ledgers, k, "up", "reps_overlap",
+                       [reps_all[s][k] for s in range(num_seeds)], r1,
+                       skip=_drop_skip(faults if faulted else None, k,
+                                       POINT_UPLOAD1, num_seeds))
+        # the server's last-seen view of every party, AFTER imputation/noise
+        # — what Eq. 10 reconstruction attends over at step ⑤
+        stale_reps = ([list(reps) for reps in reps_all] if faulted else None)
 
-    # ② server computes and sends partial gradients (+ class count C);
-    # optional label-DP-style Gaussian noise (the paper's §6 notes such
-    # defenses compose with the protocol — grad_dp_sigma exercises that)
-    grads_all = []
-    for s in range(num_seeds):
-        st_keys[s], kg = jax.random.split(st_keys[s])
-        grads = servers[s].partial_gradients(kg, reps_all[s],
-                                             splits[s].labels)
-        if cfg.grad_dp_sigma > 0:
-            noised = []
-            for g in grads:
-                st_keys[s], kn = jax.random.split(st_keys[s])
-                scale = cfg.grad_dp_sigma * jnp.std(g)
-                noised.append(g + scale * jax.random.normal(kn, g.shape))
-            grads = noised
-        grads_all.append(grads)
-    r2 = _phase_round(ledger, entry_ledgers)
-    for k in range(num_parties):
-        _log_phase(ledger, entry_ledgers, k, "down", "partial_grads",
-                   [grads_all[s][k] for s in range(num_seeds)], r2,
-                   skip=_drop_skip(faults if faulted else None, k,
-                                   POINT_SSL, num_seeds))
+    with obs.span("p2.grads"):
+        # ② server computes and sends partial gradients (+ class count C);
+        # optional label-DP-style Gaussian noise (the paper's §6 notes such
+        # defenses compose with the protocol — grad_dp_sigma exercises that)
+        grads_all = []
+        for s in range(num_seeds):
+            st_keys[s], kg = jax.random.split(st_keys[s])
+            grads = servers[s].partial_gradients(kg, reps_all[s],
+                                                 splits[s].labels)
+            if cfg.grad_dp_sigma > 0:
+                noised = []
+                for g in grads:
+                    st_keys[s], kn = jax.random.split(st_keys[s])
+                    scale = cfg.grad_dp_sigma * jnp.std(g)
+                    noised.append(g + scale * jax.random.normal(kn, g.shape))
+                grads = noised
+            grads_all.append(grads)
+        r2 = _phase_round(ledger, entry_ledgers)
+        for k in range(num_parties):
+            _log_phase(ledger, entry_ledgers, k, "down", "partial_grads",
+                       [grads_all[s][k] for s in range(num_seeds)], r2,
+                       skip=_drop_skip(faults if faulted else None, k,
+                                       POINT_SSL, num_seeds))
 
     # ③ gradient clustering → pseudo labels;  ④ local SSL — both engine-
     # side and seed-batched: the S·K gradient matrices cluster in one
     # vmapped k-means, the S·K SSL sessions fold into one stacked program
-    diags = [{"kmeans_purity": [], "ssl_metrics": [],
-              "seed_fold": num_seeds} for _ in range(num_seeds)]
-    kss = []
-    flat_kmeans_keys, flat_grads = [], []
-    for s in range(num_seeds):
-        st_keys[s], kk, ks = jax.random.split(st_keys[s], 3)
-        kss.append(ks)
-        flat_kmeans_keys.extend(jax.random.fold_in(kk, c.index)
-                                for c in clients_all[s])
-        flat_grads.extend(grads_all[s])
-    km_info: dict = {}
-    flat_pseudo = engine.pseudo_labels_seeds(
-        flat_kmeans_keys, flat_grads, splits[0].num_classes,
-        cfg.kmeans_iters, use_kernels=cfg.use_kernels, mesh=mesh,
-        info=km_info)
-    pseudo_all = engine.unflatten_seed_results(flat_pseudo, num_seeds,
-                                               num_parties)
-    for s in range(num_seeds):
-        # the k-means fold width actually run (S·K on the folded path, 1 on
-        # the ragged-shape fallback) — kernel and jnp routes alike
-        diags[s]["kernel_fold"] = km_info.get("fold", 1)
-        if "fallback" in km_info:
-            diags[s]["kernel_fallback"] = km_info["fallback"]
-    tasks_per_seed = []
-    hp = cfg.ssl_hparams()
-    for s in range(num_seeds):
-        tasks = []
-        fa = faults[s]
-        for c, pseudo, x_o, x_u in zip(clients_all[s], pseudo_all[s],
-                                       splits[s].aligned,
-                                       splits[s].unaligned):
-            diags[s]["kmeans_purity"].append(clustering.cluster_purity(
-                pseudo, splits[s].labels, splits[s].num_classes))
-            # faulted folds give EVERY party a per-step commit mask (§16):
-            # all-ones healthy, truncated straggler, all-zero dropped /
-            # representation-only — mask as data, one stacked shape
-            sv = (_fault_step_valid(fa, c.index, x_o.shape[0], hp,
-                                    skip_all=(fa is not None
-                                              and fa.skips_ssl(c.index)))
-                  if faulted else None)
-            # equal-shape overlap variants pad x_o to a fixed capacity; the
-            # split's validity mask zeroes the padded rows out of the loss
-            tasks.append(ssl_task_for(c, x_o, pseudo, x_u,
-                                      labeled_mask=splits[s].aligned_mask,
-                                      step_valid=sv))
-        diags[s]["pseudo_labels"] = pseudo_all[s]   # Ŷ_o^k — few-shot ⑤'
-        tasks_per_seed.append(tasks)                # reuses them (Alg. 2)
-    params_all, metrics_all, paths = engine.train_clients_ssl_seeds(
-        kss, tasks_per_seed, cfg.ssl_hparams(), mode=cfg.engine_mode,
-        mesh=mesh)
-    for s in range(num_seeds):
-        diags[s]["engine_path"] = paths[s]
-        diags[s]["device_fold"] = (engine.device_fold(mesh)
-                                   if paths[s] == "vmap" else 1)
-        diags[s]["ssl_metrics"].extend(metrics_all[s])
-        clients_all[s] = [replace(c, params=p)
-                          for c, p in zip(clients_all[s], params_all[s])]
+    with obs.span("p3.kmeans"):
+        diags = [{"kmeans_purity": [], "ssl_metrics": [],
+                  "seed_fold": num_seeds} for _ in range(num_seeds)]
+        kss = []
+        flat_kmeans_keys, flat_grads = [], []
+        for s in range(num_seeds):
+            st_keys[s], kk, ks = jax.random.split(st_keys[s], 3)
+            kss.append(ks)
+            flat_kmeans_keys.extend(jax.random.fold_in(kk, c.index)
+                                    for c in clients_all[s])
+            flat_grads.extend(grads_all[s])
+        km_info: dict = {}
+        flat_pseudo = engine.pseudo_labels_seeds(
+            flat_kmeans_keys, flat_grads, splits[0].num_classes,
+            cfg.kmeans_iters, use_kernels=cfg.use_kernels, mesh=mesh,
+            info=km_info)
+        pseudo_all = engine.unflatten_seed_results(flat_pseudo, num_seeds,
+                                                   num_parties)
+        for s in range(num_seeds):
+            # the k-means fold width actually run (S·K on the folded path, 1
+            # on the ragged-shape fallback) — kernel and jnp routes alike
+            diags[s]["kernel_fold"] = km_info.get("fold", 1)
+            if "fallback" in km_info:
+                diags[s]["kernel_fallback"] = km_info["fallback"]
+
+    with obs.span("p4.ssl"):
+        tasks_per_seed = []
+        hp = cfg.ssl_hparams()
+        for s in range(num_seeds):
+            tasks = []
+            fa = faults[s]
+            for c, pseudo, x_o, x_u in zip(clients_all[s], pseudo_all[s],
+                                           splits[s].aligned,
+                                           splits[s].unaligned):
+                diags[s]["kmeans_purity"].append(clustering.cluster_purity(
+                    pseudo, splits[s].labels, splits[s].num_classes))
+                # faulted folds give EVERY party a per-step commit mask (§16):
+                # all-ones healthy, truncated straggler, all-zero dropped /
+                # representation-only — mask as data, one stacked shape
+                sv = (_fault_step_valid(fa, c.index, x_o.shape[0], hp,
+                                        skip_all=(fa is not None
+                                                  and fa.skips_ssl(c.index)))
+                      if faulted else None)
+                # equal-shape overlap variants pad x_o to a fixed capacity; the
+                # split's validity mask zeroes the padded rows out of the loss
+                tasks.append(ssl_task_for(c, x_o, pseudo, x_u,
+                                          labeled_mask=splits[s].aligned_mask,
+                                          step_valid=sv))
+            diags[s]["pseudo_labels"] = pseudo_all[s]  # Ŷ_o^k — few-shot
+            tasks_per_seed.append(tasks)           # ⑤' reuses them (Alg. 2)
+        params_all, metrics_all, paths = engine.train_clients_ssl_seeds(
+            kss, tasks_per_seed, cfg.ssl_hparams(), mode=cfg.engine_mode,
+            mesh=mesh)
+        for s in range(num_seeds):
+            diags[s]["engine_path"] = paths[s]
+            diags[s]["device_fold"] = (engine.device_fold(mesh)
+                                       if paths[s] == "vmap" else 1)
+            diags[s]["ssl_metrics"].extend(metrics_all[s])
+            clients_all[s] = [replace(c, params=p)
+                              for c, p in zip(clients_all[s], params_all[s])]
 
     # ⑤ upload refreshed reps;  ⑥ server trains classifier (seed-batched).
     # Parties dropped by now upload nothing: the server reconstructs their
     # slot via Eq. 10 attention from the lowest-index survivor's refreshed
     # upload over the stale step-① payloads it still holds (§16).
-    reps_all = [[c.extract(x_o).astype(cfg.rep_dtype)
-                 for c, x_o in zip(clients_all[s], splits[s].aligned)]
-                for s in range(num_seeds)]
-    if faulted:
-        for s, fa in enumerate(faults):
-            if fa is None:
-                continue
-            for k in range(num_parties):
-                reps_all[s][k] = _dp_noised(fkeys[s], 2, k, fa,
-                                            reps_all[s][k])
-        _reconstruct_dropped(reps_all, stale_reps, faults, POINT_UPLOAD2,
-                             cfg.use_kernels)
-    r3 = _phase_round(ledger, entry_ledgers)
-    for k in range(num_parties):
-        _log_phase(ledger, entry_ledgers, k, "up", "reps_overlap_refreshed",
-                   [reps_all[s][k] for s in range(num_seeds)], r3,
-                   skip=_drop_skip(faults if faulted else None, k,
-                                   POINT_UPLOAD2, num_seeds))
-    train_classifier_seeds(k_srvs, servers, reps_all,
-                           [sp.labels for sp in splits],
-                           epochs=cfg.server_epochs,
-                           batch_size=cfg.batch_size,
-                           learning_rate=cfg.server_lr, mesh=mesh)
-    if final_reps_out is not None:
-        final_reps_out.extend(reps_all)
-
-    results = []
-    for s in range(num_seeds):
-        name, metric = _evaluate(
-            servers[s], clients_all[s], splits[s], fault=faults[s],
-            h_o_final=reps_all[s] if faulted else None,
-            fkey=fkeys[s] if faulted else None,
-            use_kernels=cfg.use_kernels)
+    with obs.span("p5.extract"):
+        reps_all = [[c.extract(x_o).astype(cfg.rep_dtype)
+                     for c, x_o in zip(clients_all[s], splits[s].aligned)]
+                    for s in range(num_seeds)]
         if faulted:
-            diags[s].update(_fault_diags(faults[s], num_parties, metric))
-        results.append(VFLResult(name, metric,
-                                 entry_ledgers[s] if faulted else ledger,
-                                 clients_all[s], servers[s], diags[s]))
+            for s, fa in enumerate(faults):
+                if fa is None:
+                    continue
+                for k in range(num_parties):
+                    reps_all[s][k] = _dp_noised(fkeys[s], 2, k, fa,
+                                                reps_all[s][k])
+            _reconstruct_dropped(reps_all, stale_reps, faults, POINT_UPLOAD2,
+                                 cfg.use_kernels)
+        r3 = _phase_round(ledger, entry_ledgers)
+        for k in range(num_parties):
+            _log_phase(ledger, entry_ledgers, k, "up",
+                       "reps_overlap_refreshed",
+                       [reps_all[s][k] for s in range(num_seeds)], r3,
+                       skip=_drop_skip(faults if faulted else None, k,
+                                       POINT_UPLOAD2, num_seeds))
+
+    with obs.span("p6.fit"):
+        train_classifier_seeds(k_srvs, servers, reps_all,
+                               [sp.labels for sp in splits],
+                               epochs=cfg.server_epochs,
+                               batch_size=cfg.batch_size,
+                               learning_rate=cfg.server_lr, mesh=mesh)
+        if final_reps_out is not None:
+            final_reps_out.extend(reps_all)
+
+    with obs.span("eval"):
+        results = []
+        for s in range(num_seeds):
+            name, metric = _evaluate(
+                servers[s], clients_all[s], splits[s], fault=faults[s],
+                h_o_final=reps_all[s] if faulted else None,
+                fkey=fkeys[s] if faulted else None,
+                use_kernels=cfg.use_kernels)
+            if faulted:
+                diags[s].update(_fault_diags(faults[s], num_parties, metric))
+            results.append(VFLResult(name, metric,
+                                     entry_ledgers[s] if faulted else ledger,
+                                     clients_all[s], servers[s], diags[s]))
     return results
 
 
@@ -670,37 +682,39 @@ def _few_shot_seeds(
     # (h_o_all IS the step-⑤ upload — same params, same dtype — and shares
     # its round: the ledger tags the unaligned payload separately but the
     # event count matches the paper's 5 comm-times; see comm.py)
-    h_u_all = [[c.extract(x).astype(cfg.rep_dtype)
-                for c, x in zip(clients_all[s], splits[s].unaligned)]
-               for s in range(num_seeds)]
-    if faulted:
-        for s, fa in enumerate(faults):
-            if fa is None:
-                continue
-            for k in range(num_parties):
-                h_u_all[s][k] = _dp_noised(fkeys[s], 3, k, fa,
-                                           h_u_all[s][k])
-    if entry_ledgers is None:   # bundled with the ⑤ upload
-        r3 = max(e.round for e in ledger.events)
-    else:
-        r3 = [max(e.round for e in led.events) for led in entry_ledgers]
-    for k in range(num_parties):
-        _log_phase(ledger, entry_ledgers, k, "up", "reps_unaligned",
-                   [h_u_all[s][k] for s in range(num_seeds)], r3,
-                   skip=_drop_skip(faults if faulted else None, k,
-                                   POINT_ROUND2, num_seeds))
+    with obs.span("f1.extract"):
+        h_u_all = [[c.extract(x).astype(cfg.rep_dtype)
+                    for c, x in zip(clients_all[s], splits[s].unaligned)]
+                   for s in range(num_seeds)]
+        if faulted:
+            for s, fa in enumerate(faults):
+                if fa is None:
+                    continue
+                for k in range(num_parties):
+                    h_u_all[s][k] = _dp_noised(fkeys[s], 3, k, fa,
+                                               h_u_all[s][k])
+        if entry_ledgers is None:   # bundled with the ⑤ upload
+            r3 = max(e.round for e in ledger.events)
+        else:
+            r3 = [max(e.round for e in led.events) for led in entry_ledgers]
+        for k in range(num_parties):
+            _log_phase(ledger, entry_ledgers, k, "up", "reps_unaligned",
+                       [h_u_all[s][k] for s in range(num_seeds)], r3,
+                       skip=_drop_skip(faults if faulted else None, k,
+                                       POINT_ROUND2, num_seeds))
 
     # ②' server fits aux classifiers f_c^k (seed-batched) and reuses the
     # joint f_c
-    kas = []
-    for s in range(num_seeds):
-        st_keys[s], ka = jax.random.split(st_keys[s])
-        kas.append(ka)
-    fit_aux_classifiers_seeds(kas, servers, h_o_all,
-                              [sp.labels for sp in splits],
-                              epochs=cfg.server_epochs,
-                              batch_size=cfg.batch_size,
-                              learning_rate=cfg.server_lr, mesh=mesh)
+    with obs.span("f2.aux"):
+        kas = []
+        for s in range(num_seeds):
+            st_keys[s], ka = jax.random.split(st_keys[s])
+            kas.append(ka)
+        fit_aux_classifiers_seeds(kas, servers, h_o_all,
+                                  [sp.labels for sp in splits],
+                                  epochs=cfg.server_epochs,
+                                  batch_size=cfg.batch_size,
+                                  learning_rate=cfg.server_lr, mesh=mesh)
 
     # ③' SDPA estimation + Eq. 8-9 gating;  ④' download p̂ — seed-batched
     # (DESIGN.md §15): per party, the S estimations + gates fold over the
@@ -708,27 +722,32 @@ def _few_shot_seeds(
     # Pallas grid launch under cfg.use_kernels — and one vmapped gate
     # session); the single-seed path is the width-1 case of the same code
     # under the same session-cache keys.
-    probs_all = [[] for _ in range(num_seeds)]
-    for s in range(num_seeds):
-        diags[s]["fewshot_gate_rate"] = []
-        diags[s]["sdpa_fold"] = num_seeds
-    h_o_stacks = [jnp.stack([h_o_all[s][j] for s in range(num_seeds)])
-                  for j in range(num_parties)]
-    r4 = _phase_round(ledger, entry_ledgers)
-    for k_idx in range(num_parties):
-        h_u_stack = jnp.stack([h_u_all[s][k_idx] for s in range(num_seeds)])
-        probs_stack = engine.fewshot_probs_seeds(
-            servers, k_idx, h_u_stack, h_o_stacks, cfg.fewshot_threshold,
-            use_kernels=cfg.use_kernels, mesh=mesh)
+    with obs.span("f3.sdpa"):
+        probs_all = [[] for _ in range(num_seeds)]
         for s in range(num_seeds):
-            probs_all[s].append(probs_stack[s])
-            diags[s]["fewshot_gate_rate"].append(
-                _safe_mean(probs_stack[s] > 0))
-        _log_phase(ledger, entry_ledgers, k_idx, "down",
-                   "pseudo_label_probs",
-                   [probs_all[s][k_idx] for s in range(num_seeds)], r4,
-                   skip=_drop_skip(faults if faulted else None, k_idx,
-                                   POINT_ROUND2, num_seeds))
+            diags[s]["fewshot_gate_rate"] = []
+            diags[s]["sdpa_fold"] = num_seeds
+        h_o_stacks = [jnp.stack([h_o_all[s][j] for s in range(num_seeds)])
+                      for j in range(num_parties)]
+        for k_idx in range(num_parties):
+            h_u_stack = jnp.stack([h_u_all[s][k_idx]
+                                   for s in range(num_seeds)])
+            probs_stack = engine.fewshot_probs_seeds(
+                servers, k_idx, h_u_stack, h_o_stacks, cfg.fewshot_threshold,
+                use_kernels=cfg.use_kernels, mesh=mesh)
+            for s in range(num_seeds):
+                probs_all[s].append(probs_stack[s])
+                diags[s]["fewshot_gate_rate"].append(
+                    _safe_mean(probs_stack[s] > 0))
+
+    with obs.span("f4.probs"):
+        r4 = _phase_round(ledger, entry_ledgers)
+        for k_idx in range(num_parties):
+            _log_phase(ledger, entry_ledgers, k_idx, "down",
+                       "pseudo_label_probs",
+                       [probs_all[s][k_idx] for s in range(num_seeds)], r4,
+                       skip=_drop_skip(faults if faulted else None, k_idx,
+                                       POINT_ROUND2, num_seeds))
 
     # ⑤' clients expand the labeled set and re-run SSL (Alg. 2 l.11-19) as
     # masked fixed-shape sessions (DESIGN.md §9): every party's labeled set
@@ -741,105 +760,108 @@ def _few_shot_seeds(
     # passing the Eq. 9 gate (p̂ > 0); fewshot_stochastic_gate restores the
     # legacy Bernoulli(p̂) subsampling for ablations. Overlap rows keep the
     # step-③ cluster pseudo-labels Ŷ_o^k (``fewshot_phase5_labels``).
-    kss = []
-    for s in range(num_seeds):
-        st_keys[s], ks = jax.random.split(st_keys[s])
-        kss.append(ks)
-    tasks_per_seed = []
-    hp = cfg.ssl_hparams()
-    for s in range(num_seeds):
-        tasks = []
-        fa = faults[s]
-        for c, probs, pseudo, x_o, x_u in zip(
-                clients_all[s], probs_all[s], diags[s]["pseudo_labels"],
-                splits[s].aligned, splits[s].unaligned):
-            if cfg.fewshot_stochastic_gate:
-                st_keys[s], kb = jax.random.split(st_keys[s])
-                take = jax.random.bernoulli(
-                    kb, jnp.clip(probs, 0.0, 1.0)).astype(jnp.float32)
-            else:
-                take = (probs > 0).astype(jnp.float32)
-            # a party absent from round 2 never received p̂: nothing gates
-            # in, and its ⑤' session commits zero steps (step_valid below)
-            skip_r2 = (fa is not None
-                       and (fa.skips_ssl(c.index)
-                            or fa.drops(c.index, POINT_ROUND2)))
-            if skip_r2:
-                take = jnp.zeros_like(take)
-            x_lab = jnp.concatenate([x_o, x_u], axis=0)
-            y_lab = fewshot_phase5_labels(c, x_o, x_u, pseudo,
-                                          cfg.fewshot_relabel_overlap)
-            # an equal-shape overlap variant's padded x_o rows stay invalid
-            # in phase ⑤' too: the overlap part of the mask is the split's
-            # validity mask instead of all-ones
-            o_mask = (jnp.ones(x_o.shape[0], jnp.float32)
-                      if splits[s].aligned_mask is None
-                      else splits[s].aligned_mask.astype(jnp.float32))
-            lab_mask = jnp.concatenate([o_mask, take])
-            sv = (_fault_step_valid(fa, c.index, x_lab.shape[0], hp,
-                                    skip_all=skip_r2)
-                  if faulted else None)
-            tasks.append(ssl_task_for(c, x_lab, y_lab, x_u,
-                                      labeled_mask=lab_mask,
-                                      unlabeled_mask=1.0 - take,
-                                      step_valid=sv))
-            diags[s].setdefault("fewshot_take_rate", []).append(
-                _safe_mean(take))
-        tasks_per_seed.append(tasks)
-    params_all, metrics_all, paths = engine.train_clients_ssl_seeds(
-        kss, tasks_per_seed, cfg.ssl_hparams(), mode=cfg.engine_mode,
-        mesh=mesh)
-    for s in range(num_seeds):
-        diags[s]["engine_path"] = paths[s]
-        diags[s]["device_fold"] = (engine.device_fold(mesh)
-                                   if paths[s] == "vmap" else 1)
-        diags[s].setdefault("ssl_metrics", []).extend(metrics_all[s])
-        clients_all[s] = [replace(c, params=p)
-                          for c, p in zip(clients_all[s], params_all[s])]
+    with obs.span("f5.ssl"):
+        kss = []
+        for s in range(num_seeds):
+            st_keys[s], ks = jax.random.split(st_keys[s])
+            kss.append(ks)
+        tasks_per_seed = []
+        hp = cfg.ssl_hparams()
+        for s in range(num_seeds):
+            tasks = []
+            fa = faults[s]
+            for c, probs, pseudo, x_o, x_u in zip(
+                    clients_all[s], probs_all[s], diags[s]["pseudo_labels"],
+                    splits[s].aligned, splits[s].unaligned):
+                if cfg.fewshot_stochastic_gate:
+                    st_keys[s], kb = jax.random.split(st_keys[s])
+                    take = jax.random.bernoulli(
+                        kb, jnp.clip(probs, 0.0, 1.0)).astype(jnp.float32)
+                else:
+                    take = (probs > 0).astype(jnp.float32)
+                # a party absent from round 2 never received p̂: nothing gates
+                # in, and its ⑤' session commits zero steps (step_valid below)
+                skip_r2 = (fa is not None
+                           and (fa.skips_ssl(c.index)
+                                or fa.drops(c.index, POINT_ROUND2)))
+                if skip_r2:
+                    take = jnp.zeros_like(take)
+                x_lab = jnp.concatenate([x_o, x_u], axis=0)
+                y_lab = fewshot_phase5_labels(c, x_o, x_u, pseudo,
+                                              cfg.fewshot_relabel_overlap)
+                # an equal-shape overlap variant's padded x_o rows stay invalid
+                # in phase ⑤' too: the overlap part of the mask is the split's
+                # validity mask instead of all-ones
+                o_mask = (jnp.ones(x_o.shape[0], jnp.float32)
+                          if splits[s].aligned_mask is None
+                          else splits[s].aligned_mask.astype(jnp.float32))
+                lab_mask = jnp.concatenate([o_mask, take])
+                sv = (_fault_step_valid(fa, c.index, x_lab.shape[0], hp,
+                                        skip_all=skip_r2)
+                      if faulted else None)
+                tasks.append(ssl_task_for(c, x_lab, y_lab, x_u,
+                                          labeled_mask=lab_mask,
+                                          unlabeled_mask=1.0 - take,
+                                          step_valid=sv))
+                diags[s].setdefault("fewshot_take_rate", []).append(
+                    _safe_mean(take))
+            tasks_per_seed.append(tasks)
+        params_all, metrics_all, paths = engine.train_clients_ssl_seeds(
+            kss, tasks_per_seed, cfg.ssl_hparams(), mode=cfg.engine_mode,
+            mesh=mesh)
+        for s in range(num_seeds):
+            diags[s]["engine_path"] = paths[s]
+            diags[s]["device_fold"] = (engine.device_fold(mesh)
+                                       if paths[s] == "vmap" else 1)
+            diags[s].setdefault("ssl_metrics", []).extend(metrics_all[s])
+            clients_all[s] = [replace(c, params=p)
+                              for c, p in zip(clients_all[s], params_all[s])]
 
     # ⑥' final upload + classifier re-fit (seed-batched). Round-2-dropped
     # parties upload nothing; their slot is Eq. 10-reconstructed from the
     # anchor's final upload over the ⑤-era overlap view (h_o_all).
-    reps_all = [[c.extract(x_o).astype(cfg.rep_dtype)
-                 for c, x_o in zip(clients_all[s], splits[s].aligned)]
-                for s in range(num_seeds)]
-    if faulted:
-        for s, fa in enumerate(faults):
-            if fa is None:
-                continue
-            for k in range(num_parties):
-                reps_all[s][k] = _dp_noised(fkeys[s], 4, k, fa,
-                                            reps_all[s][k])
-        _reconstruct_dropped(reps_all, h_o_all, faults, POINT_ROUND2,
-                             cfg.use_kernels)
-    r5 = _phase_round(ledger, entry_ledgers)
-    for k in range(num_parties):
-        _log_phase(ledger, entry_ledgers, k, "up", "reps_overlap_final",
-                   [reps_all[s][k] for s in range(num_seeds)], r5,
-                   skip=_drop_skip(faults if faulted else None, k,
-                                   POINT_ROUND2, num_seeds))
-    kfs = []
-    for s in range(num_seeds):
-        st_keys[s], kf = jax.random.split(st_keys[s])
-        kfs.append(kf)
-    train_classifier_seeds(kfs, servers, reps_all,
-                           [sp.labels for sp in splits],
-                           epochs=cfg.server_epochs,
-                           batch_size=cfg.batch_size,
-                           learning_rate=cfg.server_lr, mesh=mesh)
-
-    results = []
-    for s in range(num_seeds):
-        name, metric = _evaluate(
-            servers[s], clients_all[s], splits[s], fault=faults[s],
-            h_o_final=reps_all[s] if faulted else None,
-            fkey=fkeys[s] if faulted else None,
-            use_kernels=cfg.use_kernels)
+    with obs.span("f6.fit"):
+        reps_all = [[c.extract(x_o).astype(cfg.rep_dtype)
+                     for c, x_o in zip(clients_all[s], splits[s].aligned)]
+                    for s in range(num_seeds)]
         if faulted:
-            diags[s].update(_fault_diags(faults[s], num_parties, metric))
-        results.append(VFLResult(name, metric,
-                                 entry_ledgers[s] if faulted else ledger,
-                                 clients_all[s], servers[s], diags[s]))
+            for s, fa in enumerate(faults):
+                if fa is None:
+                    continue
+                for k in range(num_parties):
+                    reps_all[s][k] = _dp_noised(fkeys[s], 4, k, fa,
+                                                reps_all[s][k])
+            _reconstruct_dropped(reps_all, h_o_all, faults, POINT_ROUND2,
+                                 cfg.use_kernels)
+        r5 = _phase_round(ledger, entry_ledgers)
+        for k in range(num_parties):
+            _log_phase(ledger, entry_ledgers, k, "up", "reps_overlap_final",
+                       [reps_all[s][k] for s in range(num_seeds)], r5,
+                       skip=_drop_skip(faults if faulted else None, k,
+                                       POINT_ROUND2, num_seeds))
+        kfs = []
+        for s in range(num_seeds):
+            st_keys[s], kf = jax.random.split(st_keys[s])
+            kfs.append(kf)
+        train_classifier_seeds(kfs, servers, reps_all,
+                               [sp.labels for sp in splits],
+                               epochs=cfg.server_epochs,
+                               batch_size=cfg.batch_size,
+                               learning_rate=cfg.server_lr, mesh=mesh)
+
+    with obs.span("eval"):
+        results = []
+        for s in range(num_seeds):
+            name, metric = _evaluate(
+                servers[s], clients_all[s], splits[s], fault=faults[s],
+                h_o_final=reps_all[s] if faulted else None,
+                fkey=fkeys[s] if faulted else None,
+                use_kernels=cfg.use_kernels)
+            if faulted:
+                diags[s].update(_fault_diags(faults[s], num_parties, metric))
+            results.append(VFLResult(name, metric,
+                                     entry_ledgers[s] if faulted else ledger,
+                                     clients_all[s], servers[s], diags[s]))
     return results
 
 
@@ -987,6 +1009,19 @@ def run_scenarios_seeds(
                              "(FaultSpec or None) per scenario per seed")
         if not any(fa is not None for row in faults for fa in row):
             faults = None
+    name = (entry.name if entry is not None
+            else getattr(runner, "__name__", type(runner).__name__))
+    with obs.span("run", runner=name, S=num_seeds, C=num_scenarios,
+                  K=len(splits[0][0].aligned)):
+        return _run_grid(runner, impl, keys, splits, extractors, ssl_cfgs,
+                         cfg, faults, runner_kwargs)
+
+
+def _run_grid(runner, impl, keys, splits, extractors, ssl_cfgs, cfg, faults,
+              runner_kwargs) -> List[List[VFLResult]]:
+    """The C×S grid of :func:`run_scenarios_seeds`, validated: one folded
+    sweep when the flat splits share one shape, else per scenario."""
+    num_scenarios, num_seeds = len(keys), len(keys[0])
     flat_splits = [sp for row in splits for sp in row]
     if impl is not None and num_scenarios > 1 \
             and _splits_are_homogeneous(flat_splits):

@@ -52,7 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import optim
+from repro import obs, optim
 from repro.data.loader import epoch_batches
 from repro.engine import sessions
 from repro.models.extractors import Model
@@ -242,28 +242,32 @@ def train_party_ssl(key: jax.Array, task: PartyTask, hp: SSLHParams
         lambda: jax.jit(make_ssl_step_fn(task.extractor, task.head,
                                          task.ssl_cfg, tx),
                         compiler_options=ssl_compiler_options()))
-    sched = build_schedule(key, task.x_labeled.shape[0],
-                           task.x_unlabeled.shape[0], hp)
+    with obs.span("ssl.schedule"):
+        sched = build_schedule(key, task.x_labeled.shape[0],
+                               task.x_unlabeled.shape[0], hp)
     params, opt_state = task.params, tx.init(task.params)
     idx_l = np.asarray(sched.idx_labeled)
     idx_u = np.asarray(sched.idx_unlabeled)
     m_l, m_u = task.labeled_mask, task.unlabeled_mask
     sv = None if task.step_valid is None else np.asarray(task.step_valid)
     metrics: dict = {}
-    for i in range(idx_l.shape[0]):
-        # an invalid step still COMPUTES (so the recorded metrics match the
-        # vmapped session's frozen-carry step exactly) but never commits:
-        # params and optimizer state freeze together — no momentum coast
-        new_params, new_opt, m = step(
-            params, opt_state, task.feature_mean, sched.step_keys[i],
-            task.x_labeled[idx_l[i]], task.y_pseudo[idx_l[i]],
-            task.x_unlabeled[idx_u[i]],
-            None if m_l is None else m_l[idx_l[i]],
-            None if m_u is None else m_u[idx_u[i]])
-        if sv is None or sv[i] > 0:
-            params, opt_state = new_params, new_opt
-        metrics = m
-    return params, {k: float(v) for k, v in metrics.items()}
+    with obs.span("ssl.session"):
+        for i in range(idx_l.shape[0]):
+            # an invalid step still COMPUTES (so the recorded metrics match
+            # the vmapped session's frozen-carry step exactly) but never
+            # commits: params and optimizer state freeze together — no
+            # momentum coast
+            new_params, new_opt, m = step(
+                params, opt_state, task.feature_mean, sched.step_keys[i],
+                task.x_labeled[idx_l[i]], task.y_pseudo[idx_l[i]],
+                task.x_unlabeled[idx_u[i]],
+                None if m_l is None else m_l[idx_l[i]],
+                None if m_u is None else m_u[idx_u[i]])
+            if sv is None or sv[i] > 0:
+                params, opt_state = new_params, new_opt
+            metrics = m
+    with obs.span("ssl.readback"):
+        return params, {k: float(v) for k, v in metrics.items()}
 
 
 # ------------------------------------------------- fast path: vmap over clients
@@ -376,8 +380,10 @@ def train_parties_ssl_vmapped(keys: Sequence[jax.Array],
 
     tasks = parallel.pad_entries(tasks, mesh)
     keys = parallel.pad_entries(list(keys), mesh)
-    scheds = [build_schedule(kk, t.x_labeled.shape[0], t.x_unlabeled.shape[0], hp)
-              for kk, t in zip(keys, tasks)]
+    with obs.span("ssl.schedule"):
+        scheds = [build_schedule(kk, t.x_labeled.shape[0],
+                                 t.x_unlabeled.shape[0], hp)
+                  for kk, t in zip(keys, tasks)]
     if scheds[0].step_keys.shape[0] == 0:          # epochs == 0: no-op session
         return [t.params for t in tasks[:k]], [{} for _ in tasks[:k]]
     stacked_params = _stack([t.params for t in tasks])
@@ -438,17 +444,20 @@ def train_parties_ssl_vmapped(keys: Sequence[jax.Array],
         return parallel.shard_jit(jax.vmap(one_party, in_axes=axes), mesh,
                                   compiler_options=ssl_compiler_options())
 
-    fn = sessions.cached_session(
-        "ssl",
-        ("vmap", sessions.model_key(t0.extractor), sessions.model_key(t0.head),
-         t0.ssl_cfg, _optimizer_key(hp), fm is None, m_l is None, m_u is None,
-         sv is None, parallel.mesh_key(mesh)),
-        build)
-    new_params, metrics = fn(stacked_params, fm, x_l, y_l, x_u, m_l, m_u,
-                             idx_l, idx_u, step_keys, sv)
-    params_list = _unstack(new_params, k)
-    metrics_list = [{name: float(v[i]) for name, v in metrics.items()}
-                    for i in range(k)]
+    with obs.span("ssl.session"):
+        fn = sessions.cached_session(
+            "ssl",
+            ("vmap", sessions.model_key(t0.extractor),
+             sessions.model_key(t0.head), t0.ssl_cfg, _optimizer_key(hp),
+             fm is None, m_l is None, m_u is None, sv is None,
+             parallel.mesh_key(mesh)),
+            build)
+        new_params, metrics = fn(stacked_params, fm, x_l, y_l, x_u, m_l, m_u,
+                                 idx_l, idx_u, step_keys, sv)
+    with obs.span("ssl.readback"):
+        params_list = _unstack(new_params, k)
+        metrics_list = [{name: float(v[i]) for name, v in metrics.items()}
+                        for i in range(k)]
     return params_list, metrics_list
 
 
