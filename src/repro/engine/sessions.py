@@ -2,8 +2,10 @@
 
 Every whole-session jitted program in the repo — the iterative baselines'
 ``lax.scan`` sessions (``engine.iterative``), the one-shot/few-shot local-SSL
-sessions (``engine.local_ssl``), and the server classifier fits
-(``core.server._fit``) — is built once per *semantic* step identity and
+sessions (``engine.local_ssl``), the server classifier fits
+(``core.server._fit``), and the party extractors' forward behind every
+rep extraction and evaluation (``core.client.extract_program``) — is built
+once per *semantic* step identity and
 re-served from here on every later call. Training data always travels as
 arguments, never inside the cached closure, so one compiled program serves
 every seed and every scenario point of equal shapes; ``jax.jit``'s own
@@ -20,7 +22,8 @@ Cache keys combine:
   / ``IterHParams`` / ``SSLConfig``, plain floats/ints/bools).
 
 Hit/miss counters are tracked per *domain* (the first element of every
-cache key: ``"iterative"``, ``"ssl"``, ``"server_fit"``, ``"kmeans"``) so
+cache key: ``"iterative"``, ``"ssl"``, ``"server_fit"``, ``"kmeans"``,
+``"extract"``) so
 benchmarks can report compile counts per subsystem and tests can pin the
 no-recompile contract without cross-talk
 (``session_cache_stats(domain=...)``).
@@ -43,13 +46,18 @@ _SESSION_CACHE: Dict[tuple, Any] = {}
 _CACHE_STATS: Dict[str, Dict[str, int]] = {}
 
 
+class _Undigested:
+    """A closure cell ``model_key`` could not digest: a fresh instance
+    equals no other, so a key holding one never hits."""
+
+
 def _domain_stats(domain: str) -> Dict[str, int]:
     return _CACHE_STATS.setdefault(domain, {"hits": 0, "misses": 0})
 
 
 def session_cache_stats(domain: Optional[str] = None) -> Dict[str, int]:
     """Aggregate ``{"hits": .., "misses": ..}``; pass ``domain`` to restrict
-    to one subsystem ("iterative" | "ssl" | "server_fit")."""
+    to one subsystem ("iterative" | "ssl" | "server_fit" | "extract" | …)."""
     if domain is not None:
         return dict(_domain_stats(domain))
     out = {"hits": 0, "misses": 0}
@@ -97,8 +105,14 @@ def model_key(m: Model) -> tuple:
                 # guarantees a cache MISS — recompiling is safe, re-serving
                 # another model's program is not (and repr()/pointer bytes
                 # can collide across gc'd addresses)
-                cells.append(object())
+                cells.append(_Undigested())
     return (getattr(fn, "__code__", None), tuple(cells), m.rep_dim)
+
+
+def is_digested(key: tuple) -> bool:
+    """Whether a ``model_key`` result digested every closure cell, so that
+    an equal model built later gives an equal key."""
+    return not any(isinstance(c, _Undigested) for c in key[1])
 
 
 def cached_session(domain: str, key: tuple, builder: Callable[[], Any]) -> Any:
