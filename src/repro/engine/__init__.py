@@ -27,10 +27,12 @@ from repro.engine.local_ssl import (
     make_ssl_step_fn,
     parties_are_homogeneous,
     schedule_steps,
+    ssl_task_groups,
     tasks_are_homogeneous,
     train_clients_ssl,
     train_parties_ssl_vmapped,
     train_party_ssl,
+    train_task_groups,
 )
 from repro.engine.dispatch import (
     estimate_missing,
@@ -89,12 +91,14 @@ __all__ = [
     "pseudo_labels_seeds",
     "schedule_steps",
     "splitnn_sessions_seeds",
+    "ssl_task_groups",
     "stack_carries",
     "tasks_are_homogeneous",
     "train_clients_ssl",
     "train_clients_ssl_seeds",
     "train_parties_ssl_vmapped",
     "train_party_ssl",
+    "train_task_groups",
     "unflatten_seed_results",
     "unstack_carries",
 ]

@@ -6,7 +6,8 @@ i mod K of seed i // K". This module exploits that to make multi-seed
 sweeps a *compiled* capability instead of a Python loop over seeds:
 
 * :func:`train_clients_ssl_seeds` — S seeds × K parties fold into ONE
-  stacked axis of S·K entries and train as one jitted program. The session
+  stacked axis of S·K entries and train as one jitted program (per-party
+  feature dims: one S-entry program per party signature). The session
   cache (``engine.sessions``, domain ``"ssl"``) keys on semantic model
   identity + hyper-parameters, never on batch width, so seeds ≥ 2 add zero
   fresh session builds over a single-seed run (``jax.jit`` re-specializes
@@ -46,11 +47,12 @@ new engine code and zero new session-cache keys (the keys carry neither
 batch width nor data shapes, so a C ≥ 2 fold against a warm C = 1 cache
 compiles nothing fresh at the session level).
 
-Heterogeneous shapes (per-party feature dims, ragged gradient dims) fall
-back to per-entry execution — same numerics, no fold — and the fallback is
-recorded in the caller's diagnostics (``kernel_fold`` 1) plus logged once,
-never silent. The Pallas kernel path is NOT a fallback trigger anymore:
-batch is a native leading grid dimension of both kernels (DESIGN.md §15).
+Per-party feature dims split the SSL fold into one session per party
+signature. Ragged gradient dims fall back to per-entry k-means — same
+numerics, no fold — and the fallback is recorded in the caller's
+diagnostics (``kernel_fold`` 1) plus logged once, never silent. The Pallas
+kernel path is NOT a fallback trigger anymore: batch is a native leading
+grid dimension of both kernels (DESIGN.md §15).
 """
 from __future__ import annotations
 
@@ -64,7 +66,7 @@ import jax.numpy as jnp
 from repro.engine import parallel, sessions
 from repro.engine.local_ssl import (PartyParams, PartyTask, SSLHParams,
                                     tasks_are_homogeneous, train_clients_ssl,
-                                    train_parties_ssl_vmapped)
+                                    train_task_groups)
 
 
 def flatten_seed_tasks(tasks_per_seed: Sequence[Sequence[PartyTask]]
@@ -90,11 +92,14 @@ def train_clients_ssl_seeds(keys: Sequence[jax.Array],
     ``(params, metrics)`` lists plus the engine path each seed trained on.
 
     ``S == 1`` delegates verbatim to :func:`train_clients_ssl` (the
-    single-seed dispatcher — byte-for-byte the historical behavior).
-    ``S > 1`` with a homogeneous S·K task set folds everything into one
-    vmapped session; each seed's per-party keys are split exactly as the
-    single-seed dispatcher splits them, so the fold and the loop consume
-    identical schedules and PRNG streams.
+    single-seed dispatcher). ``S > 1`` flattens the S·K tasks seed-major
+    and trains one vmapped session per group of
+    :func:`~repro.engine.local_ssl.ssl_task_groups`: a homogeneous task set
+    is one S·K-entry session, a party-heterogeneous one (per-party feature
+    dims) one S-entry session per party signature. Each seed's per-party
+    keys are split exactly as the single-seed dispatcher splits them, so
+    the fold and the per-party loop (``mode="python"``, the parity oracle)
+    consume identical schedules and PRNG streams.
     """
     num_seeds = len(tasks_per_seed)
     if num_seeds == 1:
@@ -106,23 +111,17 @@ def train_clients_ssl_seeds(keys: Sequence[jax.Array],
         raise ValueError(f"unknown engine mode {mode!r}")
     k = len(tasks_per_seed[0])
     flat = flatten_seed_tasks(tasks_per_seed)
-    homogeneous = tasks_are_homogeneous(flat)
-    eff = mode
-    if mode == "auto":
-        env = os.environ.get("REPRO_ENGINE_MODE", "")
-        # multi-seed "auto" folds whenever the stacked shape exists — the
-        # whole point of batching seeds — unless the CI matrix forces the
-        # fallback loop (same knob the single-seed dispatcher honors)
-        eff = "python" if env == "python" else ("vmap" if homogeneous
-                                                else "python")
-    if eff == "vmap":
-        if not homogeneous:
-            raise ValueError("engine mode 'vmap' requires homogeneous party "
-                             "tasks across every seed of the fold; use "
-                             "mode='auto' or 'python'")
+    if mode == "vmap" and not tasks_are_homogeneous(flat):
+        raise ValueError("engine mode 'vmap' requires homogeneous party "
+                         "tasks across every seed of the fold; use "
+                         "mode='auto' or 'python'")
+    # multi-seed "auto" folds — the whole point of batching seeds — unless
+    # the CI matrix forces the loop (same knob the single-seed dispatcher
+    # honors)
+    if mode == "vmap" or (mode == "auto" and os.environ.get(
+            "REPRO_ENGINE_MODE", "") != "python"):
         flat_keys = [kk for key in keys for kk in jax.random.split(key, k)]
-        params, metrics = train_parties_ssl_vmapped(flat_keys, flat, hp,
-                                                    mesh=mesh)
+        params, metrics = train_task_groups(flat_keys, flat, hp, mesh=mesh)
         return (unflatten_seed_results(params, num_seeds, k),
                 unflatten_seed_results(metrics, num_seeds, k),
                 ["vmap"] * num_seeds)

@@ -12,15 +12,19 @@ l.28-34 / Alg. 2 l.11-19).  It is shared by
 
 Two execution paths, one set of step functions (DESIGN.md §2):
 
-  fast path      ``train_clients_ssl(..., mode="vmap")`` — all parties'
-                 params/data are stacked on a leading client axis and the
-                 whole session runs as ONE jitted program:
-                 ``vmap`` over clients × ``lax.scan`` over the step
-                 schedule, with the stacked parameter buffers donated.
-  fallback path  ``mode="python"`` — a per-client Python loop over the
-                 same jitted step, for heterogeneous zoos (per-party
-                 feature dims or extractor architectures that cannot
-                 share one stacked shape).
+  fast path      ``train_clients_ssl`` — the parties' tasks are grouped by
+                 party signature (``ssl_task_groups``: one group per set of
+                 tasks that share one stacked shape and forward), and each
+                 group's params/data stack on a leading client axis and run
+                 as ONE jitted program: ``vmap`` over the group's clients ×
+                 ``lax.scan`` over the step schedule, with the stacked
+                 parameter buffers donated. A homogeneous zoo is one group;
+                 per-party feature dims or architectures give one group
+                 per signature, each still a scanned session.
+  oracle path    ``mode="python"`` — a per-client Python loop over the
+                 same jitted step, one host dispatch per step (counted by
+                 ``obs`` as ``ssl_host_steps``): the parity reference the
+                 tests compare the fast path with.
 
 Ragged per-party *sample counts* no longer force the fallback: a
 ``PartyTask`` may carry ``labeled_mask`` / ``unlabeled_mask`` validity
@@ -233,7 +237,8 @@ def _optimizer_key(hp: SSLHParams) -> tuple:
 
 def train_party_ssl(key: jax.Array, task: PartyTask, hp: SSLHParams
                     ) -> Tuple[PartyParams, dict]:
-    """One party's SSL session as a Python loop over the cached jitted step."""
+    """One party's SSL session as a Python loop over the cached jitted step;
+    each step it dispatches counts in ``obs``'s ``ssl_host_steps``."""
     tx = make_ssl_optimizer(hp)
     step = sessions.cached_session(
         "ssl",
@@ -251,6 +256,7 @@ def train_party_ssl(key: jax.Array, task: PartyTask, hp: SSLHParams
     m_l, m_u = task.labeled_mask, task.unlabeled_mask
     sv = None if task.step_valid is None else np.asarray(task.step_valid)
     metrics: dict = {}
+    obs.count("ssl_host_steps", idx_l.shape[0])
     with obs.span("ssl.session"):
         for i in range(idx_l.shape[0]):
             # an invalid step still COMPUTES (so the recorded metrics match
@@ -301,11 +307,13 @@ def _apply_fns_match(a: Model, b: Model) -> bool:
 def tasks_are_homogeneous(tasks: Sequence[PartyTask]) -> bool:
     """True when every party's params/data/config share one stacked shape
     AND the extractor/head forward functions match — the precondition of
-    the vmap fast path. Heterogeneous zoos (per-party feature dims or
-    architectures) take the Python fallback. Ragged per-party *gate
-    counts* are NOT heterogeneous: masked tasks pad to a shared static
-    capacity (DESIGN.md §9), so their shapes — data and masks — match and
-    the fast path engages at any combination of valid-row counts."""
+    one vmapped session over all of ``tasks``. Heterogeneous zoos
+    (per-party feature dims or architectures) fail it as a whole and run
+    as one session per party signature (``ssl_task_groups``). Ragged
+    per-party *gate counts* are NOT heterogeneous: masked tasks pad to a
+    shared static capacity (DESIGN.md §9), so their shapes — data and
+    masks — match and one session serves any combination of valid-row
+    counts."""
     t0 = tasks[0]
     ref = jax.tree_util.tree_structure(t0.params)
     ref_shapes = [(l.shape, l.dtype) for l in jax.tree_util.tree_leaves(t0.params)]
@@ -461,18 +469,59 @@ def train_parties_ssl_vmapped(keys: Sequence[jax.Array],
     return params_list, metrics_list
 
 
+# ------------------------------------------- group fold: one session per group
+def ssl_task_groups(tasks: Sequence[PartyTask]) -> List[List[int]]:
+    """Partition ``tasks`` into groups that can share one stacked session:
+    indices in first-occurrence order, each task joining the first group
+    whose first member passes :func:`tasks_are_homogeneous` with it. A
+    homogeneous task set is one group; the (seed, party) tasks of a
+    party-heterogeneous fold give one group per party signature."""
+    groups: List[List[int]] = []
+    for i, task in enumerate(tasks):
+        for group in groups:
+            if tasks_are_homogeneous([tasks[group[0]], task]):
+                group.append(i)
+                break
+        else:
+            groups.append([i])
+    return groups
+
+
+def train_task_groups(keys: Sequence[jax.Array], tasks: Sequence[PartyTask],
+                      hp: SSLHParams, mesh=None
+                      ) -> Tuple[List[PartyParams], List[dict]]:
+    """Every task's session, one :func:`train_parties_ssl_vmapped` session
+    per group of :func:`ssl_task_groups` (a group of one runs the same
+    scanned session at width 1); results come back in task order, each
+    task trained with its own key, so every entry consumes the schedule
+    and PRNG stream it would on the per-party loop."""
+    params: List[Any] = [None] * len(tasks)
+    metrics: List[Any] = [None] * len(tasks)
+    for idx in ssl_task_groups(tasks):
+        rows = tasks[idx[0]].x_labeled.shape[0]
+        with obs.span("ssl.group", members=len(idx), rows=rows,
+                      steps=schedule_steps(rows, hp)):
+            p, m = train_parties_ssl_vmapped([keys[i] for i in idx],
+                                             [tasks[i] for i in idx], hp,
+                                             mesh=mesh)
+        for i, pi, mi in zip(idx, p, m):
+            params[i], metrics[i] = pi, mi
+    return params, metrics
+
+
 # ---------------------------------------------------------------- dispatcher
 def train_clients_ssl(key: jax.Array, tasks: Sequence[PartyTask],
                       hp: SSLHParams, mode: str = "auto", mesh=None
                       ) -> Tuple[List[PartyParams], List[dict], bool]:
     """Run every party's local-SSL session; returns (params, metrics, vmapped).
 
-    mode: "auto" (vmap when ``tasks_are_homogeneous``), "vmap" (require the
-    fast path; raises on heterogeneous tasks), or "python" (force the
-    per-client fallback loop). Per-party keys are split identically for
-    both paths, so "vmap" and "python" agree numerically to ~1e-5.
-    ``mesh`` (optional, DESIGN.md §14) shards the fast path's stacked
-    client axis across devices; the fallback loop ignores it.
+    mode: "auto" (one scanned session per group of ``ssl_task_groups`` —
+    a single one for a homogeneous zoo), "vmap" (require one session over
+    all parties; raises on heterogeneous tasks), or "python" (force the
+    per-client loop, the parity oracle). Per-party keys are split
+    identically for every path, so they agree numerically to ~1e-5.
+    ``mesh`` (optional, DESIGN.md §14) shards each session's stacked
+    client axis across devices; the loop ignores it.
     """
     if mode not in ("auto", "vmap", "python"):
         raise ValueError(f"unknown engine mode {mode!r}")
@@ -494,8 +543,8 @@ def train_clients_ssl(key: jax.Array, tasks: Sequence[PartyTask],
                          "use mode='auto' or 'python'")
     # explicit "vmap" always honors the request (even K=1); "auto" only
     # pays the stacked-program trace when there is >1 party to batch
-    if mode == "vmap" or (mode == "auto" and homogeneous and len(tasks) > 1):
-        params, metrics = train_parties_ssl_vmapped(keys, tasks, hp, mesh=mesh)
+    if mode == "vmap" or (mode == "auto" and len(tasks) > 1):
+        params, metrics = train_task_groups(keys, tasks, hp, mesh=mesh)
         return params, metrics, True
     params_list, metrics_list = [], []
     for kk, t in zip(keys, tasks):

@@ -15,9 +15,10 @@ The span tree of a protocol call::
     vfl.run                          core/protocol.py  run_scenarios_seeds
       vfl.init                         clients and servers
       vfl.p1.extract .. vfl.p6.fit     one-shot phases ①-⑥ (_one_shot_seeds)
-        vfl.ssl.schedule               engine/local_ssl.py, inside p4.ssl
-        vfl.ssl.session                  (and f5.ssl): schedule building,
-        vfl.ssl.readback                 the session's dispatch, its outputs
+        vfl.ssl.group                  engine/local_ssl.py, inside p4.ssl (and
+          vfl.ssl.schedule               f5.ssl): one per stacked session
+          vfl.ssl.session                (members, steps, rows), around its
+          vfl.ssl.readback               schedules, dispatch and outputs
       vfl.eval                         test evaluation
       vfl.f1.extract .. vfl.f6.fit     few-shot phases ①'-⑥' (_few_shot_seeds,
       vfl.eval                           after its one-shot pass)
@@ -28,8 +29,9 @@ another and never overlap.
 ``counters()`` gives process-wide integers for an operator who runs without
 the profiler: ``compiles`` (every backend compilation, a program loaded from
 the persistent compilation cache included), ``persistent_cache_hits`` (the
-loads among them) and the compiled-session cache's hits and misses
-(``engine.sessions``).
+loads among them), ``ssl_host_steps`` (SSL steps the per-party host loop
+dispatched one by one; 0 while every session runs scanned) and the
+compiled-session cache's hits and misses (``engine.sessions``).
 
 Rules:
 
@@ -50,7 +52,7 @@ from jax._src.lib import _profiler
 
 _BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT = "/jax/compilation_cache/cache_hits"
-_COUNTS = {"compiles": 0, "persistent_cache_hits": 0}
+_COUNTS = {"compiles": 0, "persistent_cache_hits": 0, "ssl_host_steps": 0}
 _NULL = contextlib.nullcontext()
 _RUN_IDS = itertools.count(1)
 _run = 0  # id of the protocol call in progress (its ``vfl.run``)
@@ -68,6 +70,11 @@ def _on_event(event: str, **kwargs) -> None:
 
 jax.monitoring.register_event_duration_secs_listener(_on_duration)
 jax.monitoring.register_event_listener(_on_event)
+
+
+def count(name: str, n: int) -> None:
+    """Add ``n`` to the process counter ``name``."""
+    _COUNTS[name] += n
 
 
 def counters() -> Dict[str, int]:

@@ -67,7 +67,9 @@ def test_vmap_equivalent_to_python_loop(homo_split):
 
 
 def test_auto_dispatch(homo_split, monkeypatch):
-    """auto → vmap on homogeneous zoos, Python fallback on heterogeneous.
+    """auto → one vmapped session on homogeneous zoos, one scanned session
+    per party signature on heterogeneous ones; explicit vmap still refuses
+    a heterogeneous set.
 
     Pins the DEFAULT dispatch, so the CI matrix's REPRO_ENGINE_MODE
     override (which deliberately re-steers "auto") is stripped here."""
@@ -85,9 +87,10 @@ def test_auto_dispatch(homo_split, monkeypatch):
     h_clients = _clients(jax.random.PRNGKey(1), hetero, [0, 1])
     h_tasks = _tasks(jax.random.PRNGKey(2), hetero, h_clients)
     assert not engine.tasks_are_homogeneous(h_tasks)
+    assert engine.ssl_task_groups(h_tasks) == [[0], [1]]
     _, _, vmapped = engine.train_clients_ssl(jax.random.PRNGKey(3), h_tasks,
                                              HP, mode="auto")
-    assert not vmapped
+    assert vmapped
     with pytest.raises(ValueError):
         engine.train_clients_ssl(jax.random.PRNGKey(3), h_tasks, HP,
                                  mode="vmap")

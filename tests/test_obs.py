@@ -133,14 +133,16 @@ def test_phase_spans_in_order_and_disjoint(recorded):
         assert [e.name for e in phases] == want
         for a, b in zip(phases, phases[1:]):
             assert a.end_ns <= b.start_ns
-        # the engine's spans sit inside step ④ (and ⑤'), in order
+        # the engine's spans sit inside step ④ (and ⑤'), in order: one
+        # group (both parties share a signature) around its session
         for ssl in (p for p in phases if p.name in ("p4.ssl", "f5.ssl")):
             names = [
                 e.name
                 for e, _ in inner
                 if e.name.startswith("ssl.") and ssl.start_ns <= e.start_ns < ssl.end_ns
             ]
-            assert names == ["ssl.schedule", "ssl.session", "ssl.readback"]
+            assert names == ["ssl.group", "ssl.schedule", "ssl.session",
+                             "ssl.readback"]
 
 
 def test_second_call_compiles_nothing(recorded):
