@@ -23,6 +23,7 @@ from repro.engine.local_ssl import (
     Schedule,
     SSLHParams,
     build_schedule,
+    build_schedules,
     make_ssl_optimizer,
     make_ssl_step_fn,
     parties_are_homogeneous,
@@ -75,6 +76,7 @@ __all__ = [
     "Schedule",
     "SSLHParams",
     "build_schedule",
+    "build_schedules",
     "estimate_missing",
     "estimate_missing_batched",
     "estimate_missing_fused",

@@ -34,13 +34,14 @@ rows contribute exactly zero loss, so any combination of per-party gate
 counts shares one stacked shape and the vmap fast path engages.
 
 Both paths draw their minibatch schedule and per-step PRNG keys from
-``build_schedule`` with identical per-party keys, so they are numerically
-equivalent up to batched-matmul reassociation (tests/test_engine.py pins
-this at atol 1e-5). Compiled sessions (the vmapped whole-session program
-and the fallback's per-step jit alike) are cached in the engine-wide
-session cache (``engine.sessions``, domain ``"ssl"``) keyed on semantic
-model identity + SSL/optimizer hyper-parameters, so repeated sessions
-across seeds and scenario sweeps never re-trace identical step math.
+``build_schedules`` (one host pass per group) with identical per-party
+keys, so they are numerically equivalent up to batched-matmul
+reassociation (tests/test_engine.py pins this at atol 1e-5). Compiled
+sessions (the vmapped whole-session program and the fallback's per-step
+jit alike) are cached in the engine-wide session cache
+(``engine.sessions``, domain ``"ssl"``) keyed on semantic model identity +
+SSL/optimizer hyper-parameters, so repeated sessions across seeds and
+scenario sweeps never re-trace identical step math.
 
 The stacked client axis is a plain batch axis: ``engine.batched`` folds
 S seeds × K parties of a multi-seed sweep into one S·K-entry session of
@@ -48,6 +49,7 @@ the same cached program (DESIGN.md §10).
 """
 from __future__ import annotations
 
+import functools
 import os
 from dataclasses import dataclass
 from typing import TYPE_CHECKING, Any, List, NamedTuple, Optional, Sequence, Tuple
@@ -57,7 +59,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs, optim
-from repro.data.loader import epoch_batches
 from repro.engine import sessions
 from repro.models.extractors import Model
 
@@ -112,10 +113,11 @@ class PartyTask:
 
 
 class Schedule(NamedTuple):
-    """Precomputed minibatch/PRNG schedule for one party's SSL session."""
-    idx_labeled: jnp.ndarray      # (S, bs_l) int32
-    idx_unlabeled: jnp.ndarray    # (S, bs_u) int32
-    step_keys: jnp.ndarray        # (S, 2)    per-step PRNG keys
+    """Precomputed minibatch/PRNG schedule of one party's SSL session, or of
+    a group's G sessions on a leading axis (``build_schedules``)."""
+    idx_labeled: jnp.ndarray      # ([G,] S, bs_l) int32
+    idx_unlabeled: jnp.ndarray    # ([G,] S, bs_u) int32
+    step_keys: jnp.ndarray        # ([G,] S, 2)    per-step PRNG keys
 
 
 def make_ssl_optimizer(hp: SSLHParams) -> optim.GradientTransformation:
@@ -192,40 +194,80 @@ def schedule_steps(n_labeled: int, hp: SSLHParams) -> int:
     return hp.epochs * (n_labeled // bs_l)
 
 
-def build_schedule(key: jax.Array, n_labeled: int, n_unlabeled: int,
-                   hp: SSLHParams) -> Schedule:
-    """Flatten the epoch×minibatch loop into one (S, …) step schedule.
+@functools.partial(jax.jit, static_argnums=1)
+def _seeds_and_step_keys(keys, steps: int):
+    """Every entry's host-stream seed and its ``steps`` per-step keys, in
+    one program: the draws ``randint(key, …)`` and ``split(fold_in(key, 1),
+    steps)`` make for one key at a time, bit for bit."""
+    keys = jnp.stack(keys)
+    seeds = jax.vmap(lambda k: jax.random.randint(k, (), 0, 2**31 - 1))(keys)
+    step_keys = jax.vmap(
+        lambda k: jax.random.split(jax.random.fold_in(k, 1), steps))(keys)
+    return seeds, step_keys
 
-    Labeled batches are shuffled epochs (drop-remainder); unlabeled batches
-    are independent uniform draws (FixMatch's μ× larger batches) from a
-    decorrelated stream (``_UNLABELED_STREAM``). Keys and indices are
-    materialized up front so the scan path and the Python path consume
-    bit-identical randomness. ``n_unlabeled == 0`` (a full-overlap party
-    with an empty private pool) yields zero-width unlabeled batches; the
-    masked loss path keeps them at exactly zero contribution.
+
+def build_schedules(keys: Sequence[jax.Array], n_labeled: int,
+                    n_unlabeled: int, hp: SSLHParams) -> Schedule:
+    """Flatten the epoch×minibatch loop of ``len(keys)`` entries into one
+    ``(G, S, …)`` step schedule, in one host pass.
+
+    Labeled batches are shuffled epochs (drop-remainder, as
+    ``epoch_batches`` cuts them); unlabeled batches are independent
+    uniform draws (FixMatch's μ× larger batches) from a decorrelated stream
+    (``_UNLABELED_STREAM``). Each entry's seed ``seed0`` is drawn from its
+    key; epoch ``e`` shuffles with the MT19937 stream seeded ``seed0 + e``
+    and draws its unlabeled rows from the one seeded ``seed0 + 7919*e +
+    _UNLABELED_STREAM``, so every entry gets the streams a one-key build
+    gives it (and ``bench/reference/ssl.py`` rebuilds). One generator is
+    reseeded per stream, and an epoch's unlabeled rows come from one draw
+    of ``(batches, bs_u)``: MT19937 hands out its 32-bit words with no
+    buffering between calls, so that is the per-batch draws' sequence.
+
+    Keys and indices are materialized up front so the scan path and the
+    Python path consume bit-identical randomness. ``epochs == 0`` gives an
+    empty session; ``n_unlabeled == 0`` (a full-overlap party with an empty
+    private pool) yields zero-width unlabeled batches, with no draw; the
+    masked loss path keeps them at exactly zero contribution. Each call
+    adds its host generator calls to ``obs``'s ``ssl_schedule_draws``.
     """
+    g = len(keys)
     bs_l = min(hp.batch_size, n_labeled)
     bs_u = min(hp.batch_size * hp.unlabeled_ratio, n_unlabeled)
-    seed0 = int(jax.random.randint(key, (), 0, 2**31 - 1))
-    idx_l: List[np.ndarray] = []
-    idx_u: List[np.ndarray] = []
-    for e in range(hp.epochs):
-        u_rng = np.random.RandomState(seed0 + 7919 * e + _UNLABELED_STREAM)
-        for batch in epoch_batches(n_labeled, bs_l, seed0 + e):
-            idx_l.append(batch)
-            idx_u.append(u_rng.randint(0, n_unlabeled, size=bs_u)
-                         if n_unlabeled > 0 else np.zeros(0, np.int64))
-    if not idx_l:                        # epochs == 0: an empty session
+    steps = schedule_steps(n_labeled, hp)
+    if steps == 0:                       # epochs == 0: an empty session
         return Schedule(
-            idx_labeled=jnp.zeros((0, bs_l), jnp.int32),
-            idx_unlabeled=jnp.zeros((0, bs_u), jnp.int32),
-            step_keys=jnp.zeros((0, 2), jnp.uint32),
+            idx_labeled=jnp.zeros((g, 0, bs_l), jnp.int32),
+            idx_unlabeled=jnp.zeros((g, 0, bs_u), jnp.int32),
+            step_keys=jnp.zeros((g, 0, 2), jnp.uint32),
         )
+    batches = steps // hp.epochs
+    seeds, step_keys = _seeds_and_step_keys(list(keys), steps)
+    idx_l = np.empty((g, hp.epochs, batches, bs_l), np.int32)
+    idx_u = np.zeros((g, hp.epochs, batches, bs_u), np.int32)
+    rng = np.random.RandomState(0)
+    for i, seed0 in enumerate(np.asarray(seeds).tolist()):
+        for e in range(hp.epochs):
+            rng.seed(seed0 + e)
+            idx_l[i, e] = rng.permutation(n_labeled)[:batches * bs_l].reshape(
+                batches, bs_l)
+            if n_unlabeled > 0:
+                rng.seed(seed0 + 7919 * e + _UNLABELED_STREAM)
+                idx_u[i, e] = rng.randint(0, n_unlabeled, size=(batches, bs_u))
+    obs.count("ssl_schedule_draws",
+              g * hp.epochs * (2 if n_unlabeled > 0 else 1))
     return Schedule(
-        idx_labeled=jnp.asarray(np.stack(idx_l), jnp.int32),
-        idx_unlabeled=jnp.asarray(np.stack(idx_u), jnp.int32),
-        step_keys=jax.random.split(jax.random.fold_in(key, 1), len(idx_l)),
+        idx_labeled=jnp.asarray(idx_l.reshape(g, steps, bs_l)),
+        idx_unlabeled=jnp.asarray(idx_u.reshape(g, steps, bs_u)),
+        step_keys=step_keys,
     )
+
+
+def build_schedule(key: jax.Array, n_labeled: int, n_unlabeled: int,
+                   hp: SSLHParams) -> Schedule:
+    """One entry's ``(S, …)`` schedule: :func:`build_schedules` of ``[key]``
+    with the leading axis taken off."""
+    sched = build_schedules([key], n_labeled, n_unlabeled, hp)
+    return Schedule(*(a[0] for a in sched))
 
 
 # ------------------------------------------------------- fallback: Python loop
@@ -247,9 +289,9 @@ def train_party_ssl(key: jax.Array, task: PartyTask, hp: SSLHParams
         lambda: jax.jit(make_ssl_step_fn(task.extractor, task.head,
                                          task.ssl_cfg, tx),
                         compiler_options=ssl_compiler_options()))
-    with obs.span("ssl.schedule"):
-        sched = build_schedule(key, task.x_labeled.shape[0],
-                               task.x_unlabeled.shape[0], hp)
+    n_l = task.x_labeled.shape[0]
+    with obs.span("ssl.schedule", entries=1, steps=schedule_steps(n_l, hp)):
+        sched = build_schedule(key, n_l, task.x_unlabeled.shape[0], hp)
     params, opt_state = task.params, tx.init(task.params)
     idx_l = np.asarray(sched.idx_labeled)
     idx_u = np.asarray(sched.idx_unlabeled)
@@ -388,19 +430,17 @@ def train_parties_ssl_vmapped(keys: Sequence[jax.Array],
 
     tasks = parallel.pad_entries(tasks, mesh)
     keys = parallel.pad_entries(list(keys), mesh)
-    with obs.span("ssl.schedule"):
-        scheds = [build_schedule(kk, t.x_labeled.shape[0],
-                                 t.x_unlabeled.shape[0], hp)
-                  for kk, t in zip(keys, tasks)]
-    if scheds[0].step_keys.shape[0] == 0:          # epochs == 0: no-op session
+    n_l = t0.x_labeled.shape[0]
+    with obs.span("ssl.schedule", entries=len(keys),
+                  steps=schedule_steps(n_l, hp)):
+        idx_l, idx_u, step_keys = build_schedules(
+            keys, n_l, t0.x_unlabeled.shape[0], hp)
+    if step_keys.shape[1] == 0:                    # epochs == 0: no-op session
         return [t.params for t in tasks[:k]], [{} for _ in tasks[:k]]
     stacked_params = _stack([t.params for t in tasks])
     x_l = jnp.stack([t.x_labeled for t in tasks])
     y_l = jnp.stack([t.y_pseudo for t in tasks])
     x_u = jnp.stack([t.x_unlabeled for t in tasks])
-    idx_l = jnp.stack([s.idx_labeled for s in scheds])
-    idx_u = jnp.stack([s.idx_unlabeled for s in scheds])
-    step_keys = jnp.stack([s.step_keys for s in scheds])
     fm = (None if t0.feature_mean is None
           else jnp.stack([t.feature_mean for t in tasks]))
     m_l = (None if t0.labeled_mask is None

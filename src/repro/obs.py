@@ -30,7 +30,9 @@ another and never overlap.
 the profiler: ``compiles`` (every backend compilation, a program loaded from
 the persistent compilation cache included), ``persistent_cache_hits`` (the
 loads among them), ``ssl_host_steps`` (SSL steps the per-party host loop
-dispatched one by one; 0 while every session runs scanned) and the
+dispatched one by one; 0 while every session runs scanned),
+``ssl_schedule_draws`` (host generator calls the SSL schedule builds made:
+2 per entry and epoch, 1 with an empty unlabeled pool) and the
 compiled-session cache's hits and misses (``engine.sessions``).
 
 Rules:
@@ -52,7 +54,12 @@ from jax._src.lib import _profiler
 
 _BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT = "/jax/compilation_cache/cache_hits"
-_COUNTS = {"compiles": 0, "persistent_cache_hits": 0, "ssl_host_steps": 0}
+_COUNTS = {
+    "compiles": 0,
+    "persistent_cache_hits": 0,
+    "ssl_host_steps": 0,
+    "ssl_schedule_draws": 0,
+}
 _NULL = contextlib.nullcontext()
 _RUN_IDS = itertools.count(1)
 _run = 0  # id of the protocol call in progress (its ``vfl.run``)

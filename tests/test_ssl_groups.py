@@ -148,6 +148,24 @@ def test_host_step_counter(hetero):
     assert c2 - c1 == steps == 2 * S * HP.epochs * (48 // 16)
 
 
+@pytest.mark.parametrize("empty_pool", [False, True])
+def test_schedule_draw_counter(hetero, empty_pool):
+    """A grouped run's schedule builds make 2 generator calls per entry and
+    epoch (the labeled shuffle and the unlabeled draw), 1 with no pool;
+    the per-party loop builds the same schedules and counts the same."""
+    tasks = [_tasks(s, sp) for s, sp in enumerate(hetero)]
+    if empty_pool:
+        tasks = [[dataclasses.replace(t, x_unlabeled=t.x_unlabeled[:0])
+                  for t in ts] for ts in tasks]
+    keys = [jax.random.PRNGKey(s) for s in range(S)]
+    per_entry = HP.epochs * (1 if empty_pool else 2)
+    for mode in ("auto", "python"):
+        before = obs.counters()["ssl_schedule_draws"]
+        engine.train_clients_ssl_seeds(keys, tasks, HP, mode=mode)
+        assert (obs.counters()["ssl_schedule_draws"] - before
+                == 2 * S * per_entry), mode
+
+
 def test_group_span_carries_its_attributes(hetero, tmp_path):
     from jax.profiler import ProfileData
 
@@ -165,15 +183,20 @@ def test_group_span_carries_its_attributes(hetero, tmp_path):
     (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
                         recursive=True)
     pd = ProfileData.from_file(path)
-    groups, runs = [], []
+    groups, schedules, runs = [], [], []
     for plane in pd.planes:
         for line in plane.lines:
             for e in line.events:
                 if e.name == "vfl.ssl.group":
                     groups.append(dict(e.stats))
+                elif e.name == "vfl.ssl.schedule":
+                    schedules.append(dict(e.stats))
                 elif e.name == "vfl.run":
                     runs.append(dict(e.stats))
     assert [(g["members"], g["rows"], g["steps"]) for g in groups] == [
         (S, 48, HP.epochs * 3)] * 2
+    assert [(s["entries"], s["steps"], s["ssl_schedule_draws"])
+            for s in schedules] == [(S, HP.epochs * 3, S * HP.epochs * 2)] * 2
     assert len(runs) == 1 and runs[0]["ssl_host_steps"] == 0
+    assert runs[0]["ssl_schedule_draws"] == 2 * S * HP.epochs * 2
     assert all(g["run"] == runs[0]["run"] for g in groups)
