@@ -3,11 +3,6 @@
 Pure ledger arithmetic — no training — cross-checking the implementation's
 accounting against the paper's reported numbers: one-shot = 3 comm times and
 0.79–6.3 MB; vanilla = 2 comm times/iter and 262–2094 MB; ratio ≥ 330×.
-
-One trained cross-check rides along: a tiny one-shot session is run through
-BOTH engine execution paths (vmap fast path / per-client Python loop) and
-the ledgers must record byte-identical traffic — the engine refactor cannot
-change the communication story.
 """
 from __future__ import annotations
 
@@ -49,34 +44,6 @@ def few_shot_ledger(n_o: int, n_u: int) -> CommLedger:
     return led
 
 
-def engine_paths_cross_check() -> None:
-    """Train one tiny one-shot session per engine path; assert identical
-    ledgers (and the paper's 3 comm times) out of the shared engine."""
-    import jax
-
-    from repro.core import ProtocolConfig, SSLConfig, run_one_shot
-    from repro.data import make_tabular_credit, make_vfl_partition
-    from repro.models import make_mlp_extractor
-
-    x, y = make_tabular_credit(jax.random.PRNGKey(0), 600)
-    split = make_vfl_partition(x[:, :22], y, overlap_size=64,
-                               feature_sizes=[11, 11], seed=1)
-    ext = [make_mlp_extractor(rep_dim=8, hidden=(16,)) for _ in range(2)]
-    ssl = [SSLConfig(modality="tabular")] * 2
-    ledgers = {}
-    for mode in ("vmap", "python"):
-        cfg = ProtocolConfig(client_epochs=2, server_epochs=3, engine_mode=mode)
-        res = run_one_shot(jax.random.PRNGKey(1), split, ext, ssl, cfg)
-        assert res.diagnostics["engine_path"] == mode
-        ledgers[mode] = res.ledger
-    v, p = ledgers["vmap"], ledgers["python"]
-    assert v.total_bytes() == p.total_bytes(), (v.total_bytes(), p.total_bytes())
-    assert v.comm_times() == p.comm_times() == 3
-    assert v.by_tag() == p.by_tag()
-    print(f"comm/engine_paths_agree,0,"
-          f"bytes={v.total_bytes()};times={v.comm_times()}")
-
-
 def main() -> None:
     # the paper's Tab. 1 iteration counts per overlap size
     paper_iters = {256: 4000, 512: 8000, 1024: 16000, 2048: 32000}
@@ -97,7 +64,6 @@ def main() -> None:
         print(f"comm/reduction/overlap{n_o},0,ratio={ratio:.0f}x")
         assert one.comm_times() == 3 and few.comm_times() == 5
         assert ratio > 300, ratio
-    engine_paths_cross_check()
 
 
 if __name__ == "__main__":

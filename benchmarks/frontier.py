@@ -43,7 +43,7 @@ parallel bench legs; ``bench-kernels`` / ``bench-sharded`` /
 with ``--use-kernels`` / ``--devices 2`` / the fault/* family, and
 ``bench-serving`` runs ``benchmarks.serving``)::
 
-    REPRO_ENGINE_MODE=vmap python -m benchmarks.frontier \
+    python -m benchmarks.frontier \
         --smoke --seeds 2 --check-gate
 
 ``--smoke`` runs the FULL registry catalog at CI-tractable smoke sizes
@@ -68,17 +68,10 @@ and fold-discipline checks):
   is gated alongside one-shot's;
 * one-shot's ledger bytes must not regress above the recorded baseline.
 
-Under ``REPRO_ENGINE_MODE=vmap`` it additionally requires every one-shot
-AND few-shot per-seed row to have trained on the vmapped engine path,
-every iterative/fedcvt per-seed row to have run the seed-batched ``scan``
-fold, and — on every row — ``seed_fold`` to cover the sweep's seed count
-and ``scenario_fold`` to equal the row's recorded ``group_size`` (the
-grouped sweep must not silently degrade to per-scenario loops).
-``vmap_eligible`` comes from the engine's own homogeneity predicate
-(``engine.parties_are_homogeneous`` — apply-fn identity, not the old
-shape heuristic, which would wrongly gate equal-dim model-zoo scenarios
-whose Python path is legitimate); the scan fold needs no homogeneity, so
-the iterative check is unconditional.
+It also requires, on every row, ``seed_fold`` to cover the sweep's seed
+count and ``scenario_fold`` to equal the row's recorded ``group_size``
+(the seed and scenario folds of DESIGN.md §10-12 must not silently
+degrade to per-seed or per-scenario loops).
 
 ``--devices N`` (DESIGN.md §14) shards every folded sweep's stacked
 S·C·K axis over an N-device launch mesh (forcing N host devices first on
@@ -138,9 +131,6 @@ def _aggregate_row(seed_rows) -> dict:
         metric_min=min(metrics),
         metric_max=max(metrics),
     )
-    paths = {r.get("engine_path") for r in seed_rows}
-    if len(paths) != 1:
-        row.pop("engine_path", None)   # mixed per-seed paths: don't claim one
     return row
 
 
@@ -192,13 +182,14 @@ def run_scenario_group(bundles_per_scenario, seeds, methods=METHODS,
     group_size = len(specs)
     cfgs = runner_cfgs(specs[0], methods, devices=devices,
                        use_kernels=use_kernels)
-    # the engine's own fast-path precondition: apply-fn identity + equal
+    # the engine's own homogeneity predicate: apply-fn identity + equal
     # SSL configs + equal per-party feature shapes. Heterogeneous feature
     # blocks (e.g. credit/feature-skew) — or equal-dim parties with
-    # *different* architectures — legitimately take the Python fallback,
-    # so the engine-path gate must skip those rows. ONE decision per
-    # group: the partitioner's signature makes party semantics uniform
-    # across members, so scenario 0 speaks for all of them
+    # *different* architectures — train one session per party signature
+    # and may hand k-means ragged gradients, so the kernel-fold gate skips
+    # those rows. ONE decision per group: the partitioner's signature
+    # makes party semantics uniform across members, so scenario 0 speaks
+    # for all of them
     b0 = bundles_per_scenario[0][0]
     vmap_eligible = engine.parties_are_homogeneous(
         b0.extractors, b0.ssl_cfgs, [x.shape for x in b0.split.aligned])
@@ -401,9 +392,8 @@ def check_gate(rows, baseline_path: str = BASELINE_PATH,
     accuracy ceiling, so its margins are gated alongside one-shot's.
 
     ``devices`` (a sharded ``--devices N`` sweep) additionally requires
-    every per-seed row that trained on a folded engine path ("vmap" or
-    "scan") to record ``device_fold == devices`` — the mesh must not be
-    silently dropped — and every Python-fallback row to record 1.
+    every per-seed row to record ``device_fold == devices`` — the mesh
+    must not be silently dropped.
 
     ``use_kernels`` (a ``--use-kernels`` sweep) requires the kernel path to
     have kept the fold (DESIGN.md §15): every stackable protocol row must
@@ -452,68 +442,39 @@ def check_gate(rows, baseline_path: str = BASELINE_PATH,
 
     if devices is not None:
         for r in per_seed:
-            want = devices if r.get("engine_path") in ("vmap", "scan") else 1
-            if r.get("device_fold") != want:
+            if r.get("device_fold") != devices:
                 problems.append(
-                    f"{r['scenario']} seed {r['seed']}: {r['method']} on "
-                    f"engine_path={r.get('engine_path')!r} recorded "
-                    f"device_fold={r.get('device_fold')} under "
-                    f"--devices {devices} (expected {want}) — the stacked "
-                    f"axis did not shard over the launch mesh"
+                    f"{r['scenario']} seed {r['seed']}: {r['method']} "
+                    f"recorded device_fold={r.get('device_fold')} under "
+                    f"--devices {devices} — the stacked axis did not shard "
+                    f"over the launch mesh"
                 )
 
-    if os.environ.get("REPRO_ENGINE_MODE", "") == "vmap":
-        # the CI matrix forces the fast path: every protocol method whose
-        # party zoo CAN stack must actually have trained on it — on every
-        # seed — including few-shot phase ⑤', whose masked sessions stack
-        # at any ragged per-party gate counts (heterogeneous party zoos are
-        # exempt: the Python fallback is the correct path there)
-        for r in per_seed:
-            if r["method"] in ("one_shot", "few_shot") \
-                    and r.get("vmap_eligible", False) \
-                    and r.get("engine_path") != "vmap":
-                problems.append(
-                    f"{r['scenario']} seed {r['seed']}: {r['method']} trained "
-                    f"on engine_path={r.get('engine_path')!r} under "
-                    f"REPRO_ENGINE_MODE=vmap"
-                )
-            # the iterative baselines must have run the seed-batched scan
-            # fold (DESIGN.md §11) — the scan session needs no party
-            # homogeneity, so no vmap_eligible exemption applies
-            if r["method"] in ("iterative", "fedcvt") \
-                    and r.get("engine_path") != "scan":
-                problems.append(
-                    f"{r['scenario']} seed {r['seed']}: {r['method']} trained "
-                    f"on engine_path={r.get('engine_path')!r} under "
-                    f"REPRO_ENGINE_MODE=vmap (expected the seed-batched "
-                    f"'scan' fold)"
-                )
-        # engine_path=="scan" alone cannot distinguish the fold from the
-        # per-seed fallback loop — seed_fold (the width the runner actually
-        # folded) must cover every seed of the sweep
-        num_sweep_seeds = len({r["seed"] for r in per_seed})
-        for r in per_seed:
-            fold = r.get("seed_fold")
-            if fold is not None and fold != num_sweep_seeds:
-                problems.append(
-                    f"{r['scenario']} seed {r['seed']}: {r['method']} ran "
-                    f"seed_fold={fold} — the {num_sweep_seeds}-seed sweep "
-                    f"fell back to the per-seed loop instead of the "
-                    f"DESIGN.md §10-11 fold"
-                )
-            # ... and scenario_fold must cover the row's whole partitioner
-            # group: group_size is the ground truth the bench recorded, so
-            # a mismatch means the grouped sweep silently degraded to the
-            # per-scenario loop (e.g. a shape drift broke the stack)
-            gsize = r.get("group_size")
-            if gsize is not None and r.get("scenario_fold") != gsize:
-                problems.append(
-                    f"{r['scenario']} seed {r['seed']}: {r['method']} ran "
-                    f"scenario_fold={r.get('scenario_fold')} against a "
-                    f"size-{gsize} group — the grouped sweep fell back to "
-                    f"the per-scenario loop instead of the DESIGN.md §12 "
-                    f"fold"
-                )
+    # seed_fold (the width the runner actually folded) must cover every
+    # seed of the sweep
+    num_sweep_seeds = len({r["seed"] for r in per_seed})
+    for r in per_seed:
+        fold = r.get("seed_fold")
+        if fold is not None and fold != num_sweep_seeds:
+            problems.append(
+                f"{r['scenario']} seed {r['seed']}: {r['method']} ran "
+                f"seed_fold={fold} — the {num_sweep_seeds}-seed sweep "
+                f"fell back to the per-seed loop instead of the "
+                f"DESIGN.md §10-11 fold"
+            )
+        # ... and scenario_fold must cover the row's whole partitioner
+        # group: group_size is the ground truth the bench recorded, so
+        # a mismatch means the grouped sweep silently degraded to the
+        # per-scenario loop (e.g. a shape drift broke the stack)
+        gsize = r.get("group_size")
+        if gsize is not None and r.get("scenario_fold") != gsize:
+            problems.append(
+                f"{r['scenario']} seed {r['seed']}: {r['method']} ran "
+                f"scenario_fold={r.get('scenario_fold')} against a "
+                f"size-{gsize} group — the grouped sweep fell back to "
+                f"the per-scenario loop instead of the DESIGN.md §12 "
+                f"fold"
+            )
 
     for name in scenario_names:
         ones = {r["seed"]: r for r in per_seed

@@ -13,10 +13,9 @@ headers cite is measured, not asserted. Under CPU interpret mode the
 absolute kernel numbers are interpretation overhead; the launch-count
 ratio (one dispatch vs B dispatches) is the portable signal.
 
-Engine: end-to-end wall time of one multi-client local-SSL session on the
-vmap-over-clients jitted fast path vs the per-client Python loop (both
-including trace/compile, i.e. what a protocol run actually pays) — the
-jitted path must win.
+Engine: end-to-end wall time of one multi-client local-SSL session, the
+vmap-over-clients scanned program, including trace/compile (what a
+protocol run actually pays).
 
 ``--out BENCH_kernels.json`` records every row (name, us_per_call, derived)
 plus backend/interpret context as a JSON blob.
@@ -47,7 +46,7 @@ def _emit(rows, name, us, derived=""):
 
 
 def bench_engine(rows) -> None:
-    """One homogeneous 4-party SSL session: vmap fast path vs Python loop."""
+    """One homogeneous 4-party SSL session, compile included."""
     from repro import engine
     from repro.core.ssl import SSLConfig
     from repro.models.extractors import make_classifier, make_mlp_extractor
@@ -69,19 +68,12 @@ def bench_engine(rows) -> None:
                                       feature_mean=jnp.mean(x_u, axis=0)))
     hp = engine.SSLHParams(epochs=3, batch_size=32)
 
-    def run(mode):
-        t0 = time.time()
-        params, _, vmapped = engine.train_clients_ssl(
-            jax.random.PRNGKey(1), tasks, hp, mode=mode)
-        jax.block_until_ready(jax.tree_util.tree_leaves(params))
-        return (time.time() - t0) * 1e6, vmapped
-
-    us_python, _ = run("python")
-    us_vmap, vmapped = run("vmap")
-    assert vmapped
-    _emit(rows, f"engine/ssl_python_loop/K{parties}e{hp.epochs}", us_python)
-    _emit(rows, f"engine/ssl_vmap_jit/K{parties}e{hp.epochs}", us_vmap,
-          f"speedup={us_python / us_vmap:.2f}x")
+    t0 = time.time()
+    params, _ = engine.train_task_groups(
+        list(jax.random.split(jax.random.PRNGKey(1), parties)), tasks, hp)
+    jax.block_until_ready(jax.tree_util.tree_leaves(params))
+    _emit(rows, f"engine/ssl_vmap_jit/K{parties}e{hp.epochs}",
+          (time.time() - t0) * 1e6)
 
 
 def bench_batched_grids(rows) -> None:

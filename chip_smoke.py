@@ -209,8 +209,6 @@ def train_phase(problems: list, seeds=SEEDS, budgets=None) -> dict:
             for r in _flat(grid):
                 d = r.diagnostics
                 bad = []
-                if d.get("engine_path") != "vmap":
-                    bad.append(f"engine_path={d.get('engine_path')}")
                 if d.get("kernel_fold") != want_km:
                     bad.append(f"kernel_fold={d.get('kernel_fold')} "
                                f"(want {want_km})")

@@ -15,9 +15,8 @@
 
 Every baseline executes through the engine's iterative session path
 (``repro.engine.iterative``): the whole S-iteration session is one jitted
-``lax.scan`` program (or a Python loop over the cached jitted step with
-``engine_mode="python"``), and the compiled session is cached across calls
-so scenario sweeps never recompile identical step math.
+``lax.scan`` program, and the compiled session is cached across calls so
+scenario sweeps never recompile identical step math.
 
 Each runner is the S = 1 case of a seed-batched ``*_seeds`` entry
 (DESIGN.md §11, mirroring the protocol's ``_one_shot_seeds`` pattern):
@@ -65,7 +64,6 @@ class IterativeConfig:
     fedbcd_q: int = 5               # Q (paper: 5)
     fedcvt_threshold: float = 0.95
     eval_every: int = 200
-    engine_mode: str = "auto"       # "auto" | "scan" | "python" (DESIGN.md §8)
     mesh: object = None             # device mesh for the stacked seed axis
                                     # (DESIGN.md §14): None | device count |
                                     # jax.sharding.Mesh; None consults the
@@ -223,12 +221,9 @@ def _finish_seed_results(cfg: IterativeConfig, ledger: CommLedger,
         name, metric = _evaluate(
             servers[s], clients, splits[s],
             fault=fa if fa is not None and fa.kind == "dropout" else None)
-        path = iterative.resolve_mode(cfg.engine_mode)
-        diag = {"engine_path": path,
-                "seed_fold": num_seeds,
-                "device_fold": (parallel.device_fold(
-                    parallel.resolve_mesh(cfg.mesh))
-                    if path == "scan" else 1),
+        diag = {"seed_fold": num_seeds,
+                "device_fold": parallel.device_fold(
+                    parallel.resolve_mesh(cfg.mesh)),
                 "final_loss": (float(losses[s][-1]) if losses.shape[1]
                                else None)}
         if extra_diags is not None:
@@ -287,8 +282,8 @@ def run_vanilla_seeds(
         [[c.extractor for c in cl] for cl in clients_all],
         [srv.classifier for srv in servers_all], cfg.iter_hparams(),
         carries, [sp.aligned for sp in splits],
-        [sp.labels for sp in splits], schedules, mode=cfg.engine_mode,
-        mesh=cfg.mesh, active_steps=active)
+        [sp.labels for sp in splits], schedules, mesh=cfg.mesh,
+        active_steps=active)
 
     if not faulted:
         _log_iterative_rounds(ledger, clients_all[0], cfg.iterations, bs)
@@ -365,8 +360,8 @@ def run_fedbcd_seeds(
         [[c.extractor for c in cl] for cl in clients_all],
         [srv.classifier for srv in servers_all], cfg.iter_hparams(),
         cfg.fedbcd_q, carries, [sp.aligned for sp in splits],
-        [sp.labels for sp in splits], schedules, mode=cfg.engine_mode,
-        mesh=cfg.mesh, active_steps=active)
+        [sp.labels for sp in splits], schedules, mesh=cfg.mesh,
+        active_steps=active)
 
     if not faulted:
         _log_iterative_rounds(ledger, clients_all[0], rounds, bs)
@@ -429,8 +424,8 @@ def run_fedcvt_seeds(
         [srv.classifier for srv in servers_all], cfg.iter_hparams(),
         carries, [sp.aligned for sp in splits],
         [sp.labels for sp in splits], schedules,
-        [sp.unaligned for sp in splits], u_schedules,
-        mode=cfg.engine_mode, mesh=cfg.mesh, active_steps=active)
+        [sp.unaligned for sp in splits], u_schedules, mesh=cfg.mesh,
+        active_steps=active)
 
     # overlap reps + unaligned reps up; both gradients down
     if not faulted:

@@ -7,16 +7,15 @@ server-gated pseudo-labeled unaligned samples (few-shot, Alg. 2 l.11-19).
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, replace
-from typing import Optional, Tuple
+from dataclasses import dataclass
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.ssl import SSLConfig
 from repro.engine import sessions
-from repro.engine.local_ssl import (PartyParams, PartyTask, SSLHParams,
-                                    train_party_ssl)
+from repro.engine.local_ssl import PartyParams, PartyTask
 from repro.models.extractors import Model, make_classifier
 
 # The (extractor, head) parameter pair is defined by the engine layer so the
@@ -119,28 +118,3 @@ def ssl_task_for(client: VFLClient, x_labeled: jnp.ndarray,
                      labeled_mask=labeled_mask,
                      unlabeled_mask=unlabeled_mask,
                      step_valid=step_valid)
-
-
-def local_ssl_train(
-    key: jax.Array,
-    client: VFLClient,
-    x_labeled: jnp.ndarray,
-    y_pseudo: jnp.ndarray,
-    x_unlabeled: jnp.ndarray,
-    epochs: int,
-    batch_size: int = 32,
-    learning_rate: float = 0.01,
-    momentum: float = 0.9,
-    unlabeled_ratio: int = 2,
-) -> Tuple[VFLClient, dict]:
-    """Alg. 1 lines 29-34: epochs of minibatch SSL. Labeled and unlabeled
-    minibatches are drawn independently (FixMatch uses μ=unlabeled_ratio×
-    larger unlabeled batches). Thin wrapper over the engine's single-party
-    path; ``repro.core.protocol`` batches all parties through the engine's
-    vmap fast path instead of calling this per client."""
-    hp = SSLHParams(epochs=epochs, batch_size=batch_size,
-                    learning_rate=learning_rate, momentum=momentum,
-                    unlabeled_ratio=unlabeled_ratio)
-    params, metrics = train_party_ssl(
-        key, ssl_task_for(client, x_labeled, y_pseudo, x_unlabeled), hp)
-    return replace(client, params=ClientParams(*params)), metrics

@@ -5,10 +5,10 @@ ledger-tracked client↔server exchanges (every transfer goes through the
 CommLedger so Tab. 1's communication columns are produced by the training
 code path itself) and delegate all client-side computation to the VFL
 engine layer (``repro.engine``): gradient-clustering pseudo-labels, SDPA
-estimation, and the local-SSL sessions — vmapped into one jitted program
-when the party zoo is homogeneous (including few-shot's masked
-fixed-shape phase ⑤', at any ragged per-party gate counts — DESIGN.md
-§9), per-client Python loop otherwise (DESIGN.md §2).
+estimation, and the local-SSL sessions — one vmapped, scanned program per
+party signature, a single one when the party zoo is homogeneous
+(including few-shot's masked fixed-shape phase ⑤', at any ragged
+per-party gate counts — DESIGN.md §2, §9).
 
 Both protocols are implemented once, *seed-batched* (DESIGN.md §10): the
 internal ``_one_shot_seeds`` / ``_few_shot_seeds`` drive S seeds of one
@@ -68,7 +68,6 @@ class ProtocolConfig:
     kmeans_iters: int = 25
     unlabeled_ratio: int = 2
     use_kernels: bool = False        # one switch: Pallas k-means + SDPA kernels
-    engine_mode: str = "auto"        # "auto" | "vmap" | "python" (DESIGN.md §2)
     mesh: object = None              # device mesh for the stacked engine axis
                                      # (DESIGN.md §14): None | device count |
                                      # jax.sharding.Mesh; None consults the
@@ -309,6 +308,18 @@ def _fault_diags(fault: Optional[FaultSpec], num_parties: int,
     return d
 
 
+def _commit_ssl(out, diags, clients_all, mesh) -> None:
+    """Record one ``engine.train_clients_ssl_seeds`` result (steps ④ and
+    ⑤'): each seed's SSL metrics and fold width, and its clients' new
+    params. ``out`` is indexed rather than unpacked, so a stand-in that
+    returns extra trailing values still serves."""
+    for s, (params, metrics) in enumerate(zip(out[0], out[1])):
+        diags[s]["device_fold"] = engine.device_fold(mesh)
+        diags[s].setdefault("ssl_metrics", []).extend(metrics)
+        clients_all[s] = [replace(c, params=p)
+                          for c, p in zip(clients_all[s], params)]
+
+
 def fewshot_phase5_labels(client: VFLClient, x_o: jnp.ndarray,
                           x_u: jnp.ndarray, pseudo_overlap: jnp.ndarray,
                           relabel_overlap: bool = False) -> jnp.ndarray:
@@ -488,16 +499,9 @@ def _one_shot_seeds(
                                           step_valid=sv))
             diags[s]["pseudo_labels"] = pseudo_all[s]  # Ŷ_o^k — few-shot
             tasks_per_seed.append(tasks)           # ⑤' reuses them (Alg. 2)
-        params_all, metrics_all, paths = engine.train_clients_ssl_seeds(
-            kss, tasks_per_seed, cfg.ssl_hparams(), mode=cfg.engine_mode,
-            mesh=mesh)
-        for s in range(num_seeds):
-            diags[s]["engine_path"] = paths[s]
-            diags[s]["device_fold"] = (engine.device_fold(mesh)
-                                       if paths[s] == "vmap" else 1)
-            diags[s]["ssl_metrics"].extend(metrics_all[s])
-            clients_all[s] = [replace(c, params=p)
-                              for c, p in zip(clients_all[s], params_all[s])]
+        _commit_ssl(engine.train_clients_ssl_seeds(
+            kss, tasks_per_seed, cfg.ssl_hparams(), mesh=mesh),
+            diags, clients_all, mesh)
 
     # ⑤ upload refreshed reps;  ⑥ server trains classifier (seed-batched).
     # Parties dropped by now upload nothing: the server reconstructs their
@@ -754,8 +758,8 @@ def _few_shot_seeds(
     # is the full (x_o ∘ x_u) at the static capacity N_o + N_u with a
     # validity mask [1…1 ∘ gate], and the unlabeled set stays the full
     # private pool with the complementary mask — so ragged per-party gate
-    # counts share one stacked shape, the vmap fast path engages under any
-    # engine_mode, and an all-gated pool is simply a zero-valid unlabeled
+    # counts share one stacked shape and one scanned session, and an
+    # all-gated pool is simply a zero-valid unlabeled
     # mask (no row ever sits in both sets). The paper keeps *every* sample
     # passing the Eq. 9 gate (p̂ > 0); fewshot_stochastic_gate restores the
     # legacy Bernoulli(p̂) subsampling for ablations. Overlap rows keep the
@@ -806,16 +810,9 @@ def _few_shot_seeds(
                 diags[s].setdefault("fewshot_take_rate", []).append(
                     _safe_mean(take))
             tasks_per_seed.append(tasks)
-        params_all, metrics_all, paths = engine.train_clients_ssl_seeds(
-            kss, tasks_per_seed, cfg.ssl_hparams(), mode=cfg.engine_mode,
-            mesh=mesh)
-        for s in range(num_seeds):
-            diags[s]["engine_path"] = paths[s]
-            diags[s]["device_fold"] = (engine.device_fold(mesh)
-                                       if paths[s] == "vmap" else 1)
-            diags[s].setdefault("ssl_metrics", []).extend(metrics_all[s])
-            clients_all[s] = [replace(c, params=p)
-                              for c, p in zip(clients_all[s], params_all[s])]
+        _commit_ssl(engine.train_clients_ssl_seeds(
+            kss, tasks_per_seed, cfg.ssl_hparams(), mesh=mesh),
+            diags, clients_all, mesh)
 
     # ⑥' final upload + classifier re-fit (seed-batched). Round-2-dropped
     # parties upload nothing; their slot is Eq. 10-reconstructed from the

@@ -29,7 +29,7 @@ from typing import Any, Dict, Mapping, Optional
 KINDS = ("train", "serving")
 
 #: execution diagnostics a training row forwards from ``VFLResult``
-DIAGNOSTIC_KEYS = ("iterations", "engine_path", "seed_fold", "scenario_fold",
+DIAGNOSTIC_KEYS = ("iterations", "seed_fold", "scenario_fold",
                    "device_fold", "kernel_fold", "kernel_fallback",
                    "sdpa_fold",
                    # fault-injection diagnostics (DESIGN.md §16)

@@ -32,7 +32,6 @@ from repro.engine.local_ssl import (
     tasks_are_homogeneous,
     train_clients_ssl,
     train_parties_ssl_vmapped,
-    train_party_ssl,
     train_task_groups,
 )
 from repro.engine.dispatch import (
@@ -99,7 +98,6 @@ __all__ = [
     "train_clients_ssl",
     "train_clients_ssl_seeds",
     "train_parties_ssl_vmapped",
-    "train_party_ssl",
     "train_task_groups",
     "unflatten_seed_results",
     "unstack_carries",

@@ -57,7 +57,6 @@ grid dimension of both kernels (DESIGN.md §15).
 from __future__ import annotations
 
 import logging
-import os
 from typing import Any, List, Sequence, Tuple
 
 import jax
@@ -65,7 +64,6 @@ import jax.numpy as jnp
 
 from repro.engine import parallel, sessions
 from repro.engine.local_ssl import (PartyParams, PartyTask, SSLHParams,
-                                    tasks_are_homogeneous, train_clients_ssl,
                                     train_task_groups)
 
 
@@ -85,54 +83,25 @@ def unflatten_seed_results(flat: Sequence[Any], num_seeds: int,
 # ------------------------------------------------------- SSL: the S·K fold
 def train_clients_ssl_seeds(keys: Sequence[jax.Array],
                             tasks_per_seed: Sequence[Sequence[PartyTask]],
-                            hp: SSLHParams, mode: str = "auto", mesh=None
+                            hp: SSLHParams, mesh=None
                             ) -> Tuple[List[List[PartyParams]],
-                                       List[List[dict]], List[str]]:
+                                       List[List[dict]]]:
     """Every seed's every party's SSL session; returns per-seed
-    ``(params, metrics)`` lists plus the engine path each seed trained on.
+    ``(params, metrics)`` lists.
 
-    ``S == 1`` delegates verbatim to :func:`train_clients_ssl` (the
-    single-seed dispatcher). ``S > 1`` flattens the S·K tasks seed-major
-    and trains one vmapped session per group of
-    :func:`~repro.engine.local_ssl.ssl_task_groups`: a homogeneous task set
-    is one S·K-entry session, a party-heterogeneous one (per-party feature
-    dims) one S-entry session per party signature. Each seed's per-party
-    keys are split exactly as the single-seed dispatcher splits them, so
-    the fold and the per-party loop (``mode="python"``, the parity oracle)
-    consume identical schedules and PRNG streams.
+    The S·K tasks flatten seed-major and train as one vmapped session per
+    group of :func:`~repro.engine.local_ssl.ssl_task_groups`: a homogeneous
+    task set is one S·K-entry session, a party-heterogeneous one (per-party
+    feature dims) one S-entry session per party signature. Each seed's key
+    splits into its K per-party keys exactly as
+    :func:`~repro.engine.local_ssl.train_clients_ssl` splits one key.
     """
-    num_seeds = len(tasks_per_seed)
-    if num_seeds == 1:
-        params, metrics, vmapped = train_clients_ssl(
-            keys[0], tasks_per_seed[0], hp, mode=mode, mesh=mesh)
-        return [params], [metrics], ["vmap" if vmapped else "python"]
-
-    if mode not in ("auto", "vmap", "python"):
-        raise ValueError(f"unknown engine mode {mode!r}")
     k = len(tasks_per_seed[0])
-    flat = flatten_seed_tasks(tasks_per_seed)
-    if mode == "vmap" and not tasks_are_homogeneous(flat):
-        raise ValueError("engine mode 'vmap' requires homogeneous party "
-                         "tasks across every seed of the fold; use "
-                         "mode='auto' or 'python'")
-    # multi-seed "auto" folds — the whole point of batching seeds — unless
-    # the CI matrix forces the loop (same knob the single-seed dispatcher
-    # honors)
-    if mode == "vmap" or (mode == "auto" and os.environ.get(
-            "REPRO_ENGINE_MODE", "") != "python"):
-        flat_keys = [kk for key in keys for kk in jax.random.split(key, k)]
-        params, metrics = train_task_groups(flat_keys, flat, hp, mesh=mesh)
-        return (unflatten_seed_results(params, num_seeds, k),
-                unflatten_seed_results(metrics, num_seeds, k),
-                ["vmap"] * num_seeds)
-    out_p, out_m, paths = [], [], []
-    for key, tasks in zip(keys, tasks_per_seed):
-        params, metrics, vmapped = train_clients_ssl(key, tasks, hp,
-                                                     mode=mode)
-        out_p.append(params)
-        out_m.append(metrics)
-        paths.append("vmap" if vmapped else "python")
-    return out_p, out_m, paths
+    flat_keys = [kk for key in keys for kk in jax.random.split(key, k)]
+    params, metrics = train_task_groups(
+        flat_keys, flatten_seed_tasks(tasks_per_seed), hp, mesh=mesh)
+    return (unflatten_seed_results(params, len(tasks_per_seed), k),
+            unflatten_seed_results(metrics, len(tasks_per_seed), k))
 
 
 # --------------------------------------------- k-means: fold over the batch
@@ -299,8 +268,7 @@ def _assert_seed_models_equal(extractors_per_seed, classifiers) -> None:
 
 def splitnn_sessions_seeds(extractors_per_seed, classifiers,
                            hp, carries: Sequence[Any],
-                           xs_per_seed, ys, schedules,
-                           mode: str = "auto", mesh=None,
+                           xs_per_seed, ys, schedules, mesh=None,
                            active_steps=None):
     """S seeds of one SplitNN session as ONE folded program.
 
@@ -320,7 +288,7 @@ def splitnn_sessions_seeds(extractors_per_seed, classifiers,
         iterative.session_cache_key("splitnn", exts, clf, hp),
         lambda: iterative.make_splitnn_step_fn(exts, clf, hp),
         stack_carries(carries), _stack_party_data(xs_per_seed),
-        jnp.stack(list(ys)), jnp.stack(list(schedules)), mode, mesh=mesh,
+        jnp.stack(list(ys)), jnp.stack(list(schedules)), mesh=mesh,
         active_steps=active_steps)
     return unstack_carries(carry, len(carries)), losses
 
@@ -328,8 +296,7 @@ def splitnn_sessions_seeds(extractors_per_seed, classifiers,
 def fedcvt_sessions_seeds(extractors_per_seed, classifiers, hp,
                           carries: Sequence[Any], xs_per_seed, ys,
                           schedules, xs_u_per_seed, u_schedules,
-                          mode: str = "auto", mesh=None,
-                          active_steps=None):
+                          mesh=None, active_steps=None):
     """S seeds of one FedCVT-style session as ONE folded program; the
     per-party unaligned pools and their draw schedules stack on the same
     seed axis. ``active_steps`` as in :func:`splitnn_sessions_seeds`.
@@ -343,7 +310,7 @@ def fedcvt_sessions_seeds(extractors_per_seed, classifiers, hp,
         iterative.session_cache_key("fedcvt", exts, clf, hp),
         lambda: iterative.make_fedcvt_step_fn(exts, clf, hp),
         stack_carries(carries), _stack_party_data(xs_per_seed),
-        jnp.stack(list(ys)), jnp.stack(list(schedules)), mode,
+        jnp.stack(list(ys)), jnp.stack(list(schedules)),
         xs_u=_stack_party_data(xs_u_per_seed),
         u_schedules=tuple(jnp.stack([us[k] for us in u_schedules])
                           for k in range(num_parties)), mesh=mesh,
@@ -353,8 +320,7 @@ def fedcvt_sessions_seeds(extractors_per_seed, classifiers, hp,
 
 def fedbcd_sessions_seeds(extractors_per_seed, classifiers, hp, q: int,
                           carries: Sequence[Any], xs_per_seed, ys,
-                          schedules, mode: str = "auto", mesh=None,
-                          active_steps=None):
+                          schedules, mesh=None, active_steps=None):
     """S seeds of one FedBCD-p session (Q local updates per round) as ONE
     folded program. ``active_steps`` as in :func:`splitnn_sessions_seeds`
     (units: communication ROUNDS). Returns ``(per-seed carries,
@@ -367,7 +333,7 @@ def fedbcd_sessions_seeds(extractors_per_seed, classifiers, hp, q: int,
         iterative.session_cache_key("fedbcd", exts, clf, hp, q),
         lambda: iterative.make_fedbcd_step_fn(exts, clf, hp, q),
         stack_carries(carries), _stack_party_data(xs_per_seed),
-        jnp.stack(list(ys)), jnp.stack(list(schedules)), mode, mesh=mesh,
+        jnp.stack(list(ys)), jnp.stack(list(schedules)), mesh=mesh,
         active_steps=active_steps)
     return unstack_carries(carry, len(carries)), losses
 

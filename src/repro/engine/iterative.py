@@ -17,10 +17,8 @@ point. This module is the iterative counterpart of ``engine.local_ssl``
   pseudo-label confidence clears a threshold;
 * ``make_fedbcd_step_fn`` — FedBCD-p [20]: one rep/partial-gradient
   exchange then Q parallel stale-gradient local updates per round;
-* ``run_iterative_session`` — executes S iterations either as one jitted
-  ``lax.scan`` over a precomputed minibatch schedule (``"scan"``, the
-  fast path) or as a Python loop over the cached jitted step
-  (``"python"``);
+* ``run_iterative_session`` — executes S iterations as one jitted
+  ``lax.scan`` over a precomputed minibatch schedule;
 * ``run_iterative_session_seeds`` — the seed-axis fold (DESIGN.md §11):
   every array argument carries a leading seed axis and the whole
   multi-seed session runs as ONE ``vmap``-of-``lax.scan`` program. The
@@ -34,16 +32,14 @@ identity of the party models (apply-fn code object + closure cells — the
 same guarantee ``local_ssl._apply_fns_match`` relies on) plus the
 optimizer hyper-parameters, so repeated sessions (another seed, another
 scenario point with equal minibatch shapes) re-use the compiled program
-instead of re-tracing. ``session_cache_stats()`` exposes hit/miss
-counters; tests pin the no-recompile contract with them.
+instead of re-tracing. ``sessions.session_cache_stats("iterative")``
+exposes hit/miss counters; tests pin the no-recompile contract with them.
 
 Communication stays host-side: callers log per-round ledger events
-around the jitted session, so both execution modes produce byte-identical
-CommLedgers (the engine-refactor invariant of ``benchmarks/comm_cost``).
+around the jitted session, so the ledger is a function of shapes alone.
 """
 from __future__ import annotations
 
-import os
 from dataclasses import dataclass
 from typing import Callable, List, Optional, Sequence, Tuple
 
@@ -65,46 +61,6 @@ class IterHParams:
     server_lr: float = 0.01
     momentum: float = 0.9
     fedcvt_threshold: float = 0.95
-
-
-def resolve_mode(mode: str) -> str:
-    """Map a requested engine mode onto an iterative execution path.
-
-    ``"scan"`` (and the protocol layer's ``"vmap"``, its analogue for the
-    one-shot engine) → the fused lax.scan session; ``"python"`` → per-step
-    loop over the cached jitted step. ``"auto"`` honors the CI matrix knob
-    ``REPRO_ENGINE_MODE`` and otherwise takes the fast path.
-    """
-    if mode == "python":
-        return "python"
-    if mode in ("scan", "vmap"):
-        return "scan"
-    if mode == "auto":
-        env = os.environ.get("REPRO_ENGINE_MODE", "")
-        return "python" if env == "python" else "scan"
-    raise ValueError(f"unknown iterative engine mode {mode!r}")
-
-
-# ----------------------------------------------------------- session cache
-# The cache itself lives in ``engine.sessions`` (shared with the SSL and
-# server-fit sessions); this module's historical API keeps its historical
-# *scope* — stats over the iterative sessions only, so callers that
-# interleave SSL/server fits between clear and assert see unchanged counts.
-_model_key = sessions.model_key
-
-
-def session_cache_stats() -> dict:
-    return sessions.session_cache_stats("iterative")
-
-
-def clear_session_cache() -> None:
-    """Clears the whole engine-wide cache (all domains) — the conservative
-    reading of the historical contract; per-domain stats reset with it."""
-    sessions.clear_session_cache()
-
-
-def _cached(key: tuple, builder: Callable[[], Callable]) -> Callable:
-    return sessions.cached_session("iterative", key, builder)
 
 
 # ------------------------------------------------------------ step factories
@@ -270,7 +226,7 @@ def build_iteration_schedule(seed: int, n: int, batch_size: int,
                              iterations: int) -> jnp.ndarray:
     """(S, bs) int32 minibatch indices: shuffled epochs, drop-remainder,
     truncated/cycled to exactly ``iterations`` rows — materialized up front
-    so the scan path and the Python path consume identical batches."""
+    so every seed's batches travel into the session as data."""
     bs = min(batch_size, n)
     if iterations <= 0:                      # a no-op session is valid
         return jnp.zeros((0, bs), jnp.int32)
@@ -310,7 +266,6 @@ def run_iterative_session_seeds(
     xs: Sequence[jnp.ndarray],
     y: jnp.ndarray,
     schedule: jnp.ndarray,
-    mode: str = "auto",
     xs_u: Optional[Sequence[jnp.ndarray]] = None,
     u_schedules: Optional[Sequence[jnp.ndarray]] = None,
     mesh=None,
@@ -334,25 +289,22 @@ def run_iterative_session_seeds(
     ``(S, iters, bs)`` — per-seed randomness lives in the schedule
     *contents*, which travel as arguments, never in the compiled program.
 
-    ``"scan"`` executes ONE cached ``jax.vmap``-of-``lax.scan`` program
-    under the SAME session-cache key as the historical single-seed scan
-    session (the key has no batch width, so folding seeds adds zero fresh
-    session builds; ``jax.jit`` re-specializes the cached program per
-    stacked shape). ``"python"`` loops seeds × steps over the cached
-    jitted step — byte-for-byte the historical per-seed fallback.
+    It executes ONE cached ``jax.vmap``-of-``lax.scan`` program under the
+    SAME session-cache key as the single-seed session (the key has no
+    batch width, so folding seeds adds zero fresh session builds;
+    ``jax.jit`` re-specializes the cached program per stacked shape).
 
-    With a resolved ``mesh`` the ``"scan"`` path shards the seed axis over
-    the device mesh (DESIGN.md §14): stacked arguments pad to a
-    device-count multiple with copies of seed 0, the vmap-of-scan runs
-    under ``shard_map``, and results are stripped back host-side. The
-    cache key gains the mesh identity; ``"python"`` ignores the mesh.
+    With a resolved ``mesh`` the seed axis shards over the device mesh
+    (DESIGN.md §14): stacked arguments pad to a device-count multiple
+    with copies of seed 0, the vmap-of-scan runs under ``shard_map``, and
+    results are stripped back host-side. The cache key gains the mesh
+    identity.
 
     Returns ``(carry, losses)`` with the same stacking and ``losses`` of
     shape ``(S, iters)``.
     """
     from repro.engine import parallel        # sibling: mesh plumbing
 
-    mode = resolve_mode(mode)
     mesh = parallel.resolve_mesh(mesh)
     xs = tuple(xs)
     num_seeds = schedule.shape[0]
@@ -363,34 +315,7 @@ def run_iterative_session_seeds(
         xs_u = tuple(xs_u)
         u_schedules = tuple(u_schedules)
 
-    if mode == "python":
-        step = _cached(("step", has_u) + cache_key,
-                       lambda: jax.jit(make_step()))
-        act = (None if active_steps is None
-               else np.asarray(active_steps, np.int64))
-        out_carries, out_losses = [], []
-        for s in range(num_seeds):
-            c = jax.tree_util.tree_map(lambda a: a[s], carry)
-            sched = np.asarray(schedule[s])
-            u_scheds = ([np.asarray(us[s]) for us in u_schedules]
-                        if has_u else None)
-            losses = []
-            for i in range(sched.shape[0]):
-                xb = tuple(x[s][sched[i]] for x in xs)
-                xub = (tuple(xu[s][us[i]] for xu, us in zip(xs_u, u_scheds))
-                       if has_u else None)
-                # a stalled step still computes (matching the scan path's
-                # frozen-carry loss exactly) but never commits the carry
-                new_c, loss = step(c, xb, y[s][sched[i]], xub)
-                if act is None or i < act[s]:
-                    c = new_c
-                losses.append(loss)
-            out_carries.append(c)
-            out_losses.append(jnp.stack(losses))
-        return (jax.tree_util.tree_map(lambda *a: jnp.stack(a), *out_carries),
-                jnp.stack(out_losses))
-
-    # "scan": the whole multi-seed session is one jitted program with a
+    # the whole multi-seed session is one jitted program with a
     # donated stacked carry — vmap's batch axis IS the seed axis. Under a
     # mesh that axis pads to a device-count multiple and shards (§14).
     # A faulted session (active_steps given) is a distinct cached program
@@ -436,7 +361,8 @@ def run_iterative_session_seeds(
 
             return parallel.shard_jit(jax.vmap(session), mesh)
 
-        session = _cached(("scan", True) + fkey + cache_key + mkey, build)
+        session = sessions.cached_session(
+            "iterative", ("scan", True) + fkey + cache_key + mkey, build)
         args = (parallel.pad_stacked(carry, pad),
                 parallel.pad_stacked(xs, pad),
                 parallel.pad_stacked(y, pad),
@@ -470,7 +396,8 @@ def run_iterative_session_seeds(
 
         return parallel.shard_jit(jax.vmap(session), mesh)
 
-    session = _cached(("scan", False) + fkey + cache_key + mkey, build)
+    session = sessions.cached_session(
+        "iterative", ("scan", False) + fkey + cache_key + mkey, build)
     args = (parallel.pad_stacked(carry, pad), parallel.pad_stacked(xs, pad),
             parallel.pad_stacked(y, pad), parallel.pad_stacked(schedule, pad))
     out, losses = session(*(args + (active,) if faulted else args))
@@ -484,7 +411,6 @@ def run_iterative_session(
     xs: Sequence[jnp.ndarray],
     y: jnp.ndarray,
     schedule: jnp.ndarray,
-    mode: str = "auto",
     xs_u: Optional[Sequence[jnp.ndarray]] = None,
     u_schedules: Optional[Sequence[jnp.ndarray]] = None,
 ):
@@ -507,7 +433,7 @@ def run_iterative_session(
     carry1 = jax.tree_util.tree_map(lambda a: jnp.asarray(a)[None], carry)
     out, losses = run_iterative_session_seeds(
         cache_key, make_step, carry1, tuple(x[None] for x in xs), y[None],
-        schedule[None], mode,
+        schedule[None],
         xs_u=(tuple(x[None] for x in xs_u) if has_u else None),
         u_schedules=(tuple(s[None] for s in u_schedules)
                      if has_u else None))
@@ -522,24 +448,24 @@ def session_cache_key(kind: str, extractors, classifier, hp: IterHParams,
     (``engine.batched.*_sessions_seeds``) build their keys here, so the
     width-1 program and the fold can never drift onto separate cache
     entries."""
-    key = (kind, tuple(_model_key(e) for e in extractors),
-           _model_key(classifier), hp)
+    key = (kind, tuple(sessions.model_key(e) for e in extractors),
+           sessions.model_key(classifier), hp)
     return key if q is None else key + (int(q),)
 
 
 def splitnn_session(extractors, classifier, hp: IterHParams, carry, xs, y,
-                    schedule, mode: str = "auto"):
+                    schedule):
     """SplitNN session with the cache key derived from model semantics."""
     return run_iterative_session(
         session_cache_key("splitnn", extractors, classifier, hp),
         lambda: make_splitnn_step_fn(extractors, classifier, hp),
-        carry, xs, y, schedule, mode)
+        carry, xs, y, schedule)
 
 
 def fedcvt_session(extractors, classifier, hp: IterHParams, carry, xs, y,
-                   schedule, xs_u, u_schedules, mode: str = "auto"):
+                   schedule, xs_u, u_schedules):
     """FedCVT-style session with the cache key derived from model semantics."""
     return run_iterative_session(
         session_cache_key("fedcvt", extractors, classifier, hp),
         lambda: make_fedcvt_step_fn(extractors, classifier, hp),
-        carry, xs, y, schedule, mode, xs_u=xs_u, u_schedules=u_schedules)
+        carry, xs, y, schedule, xs_u=xs_u, u_schedules=u_schedules)

@@ -4,41 +4,32 @@ This module is the single place the repo implements "client trains its
 extractor+head by semi-supervised learning on pseudo-labels" (Alg. 1
 l.28-34 / Alg. 2 l.11-19).  It is shared by
 
-  * ``repro.core.protocol`` / ``repro.core.client`` — the host-scale
-    protocol orchestrators (``local_ssl_train`` delegates here);
+  * ``repro.core.protocol`` — the host-scale protocol orchestrator;
   * ``repro.launch.vfl_step`` — the multi-pod shard_map schedule, which
     closes the same ``make_ssl_step_fn`` step inside its ``lax.fori_loop``
     so the collective-count story is measured against the real step math.
 
-Two execution paths, one set of step functions (DESIGN.md §2):
+One execution path, one set of step functions (DESIGN.md §2): the
+parties' tasks are grouped by party signature (``ssl_task_groups``: one
+group per set of tasks that share one stacked shape and forward), and each
+group's params/data stack on a leading client axis and run as ONE jitted
+program: ``vmap`` over the group's clients × ``lax.scan`` over the step
+schedule, with the stacked parameter buffers donated. A homogeneous zoo is
+one group; per-party feature dims or architectures give one group per
+signature, and a single party is a width-1 group, each still a scanned
+session.
 
-  fast path      ``train_clients_ssl`` — the parties' tasks are grouped by
-                 party signature (``ssl_task_groups``: one group per set of
-                 tasks that share one stacked shape and forward), and each
-                 group's params/data stack on a leading client axis and run
-                 as ONE jitted program: ``vmap`` over the group's clients ×
-                 ``lax.scan`` over the step schedule, with the stacked
-                 parameter buffers donated. A homogeneous zoo is one group;
-                 per-party feature dims or architectures give one group
-                 per signature, each still a scanned session.
-  oracle path    ``mode="python"`` — a per-client Python loop over the
-                 same jitted step, one host dispatch per step (counted by
-                 ``obs`` as ``ssl_host_steps``): the parity reference the
-                 tests compare the fast path with.
+Ragged per-party *sample counts* do not split a group: a ``PartyTask`` may
+carry ``labeled_mask`` / ``unlabeled_mask`` validity masks over data padded
+to a static capacity (DESIGN.md §9 — few-shot phase ⑤' pads every party's
+gated labeled set to N_o + N_u), and masked rows contribute exactly zero
+loss, so any combination of per-party gate counts shares one stacked shape.
 
-Ragged per-party *sample counts* no longer force the fallback: a
-``PartyTask`` may carry ``labeled_mask`` / ``unlabeled_mask`` validity
-masks over data padded to a static capacity (DESIGN.md §9 — few-shot
-phase ⑤' pads every party's gated labeled set to N_o + N_u), and masked
-rows contribute exactly zero loss, so any combination of per-party gate
-counts shares one stacked shape and the vmap fast path engages.
-
-Both paths draw their minibatch schedule and per-step PRNG keys from
-``build_schedules`` (one host pass per group) with identical per-party
-keys, so they are numerically equivalent up to batched-matmul
-reassociation (tests/test_engine.py pins this at atol 1e-5). Compiled
-sessions (the vmapped whole-session program and the fallback's per-step
-jit alike) are cached in the engine-wide session cache
+Every session draws its minibatch schedule and per-step PRNG keys from
+``build_schedules`` (one host pass per group) with per-party keys, so the
+tests' per-step host loop over the same step (``tests/engine_oracles.py``)
+matches the session up to batched-matmul reassociation (atol 1e-5).
+Compiled sessions are cached in the engine-wide session cache
 (``engine.sessions``, domain ``"ssl"``) keyed on semantic model identity +
 SSL/optimizer hyper-parameters, so repeated sessions across seeds and
 scenario sweeps never re-trace identical step math.
@@ -50,7 +41,6 @@ the same cached program (DESIGN.md §10).
 from __future__ import annotations
 
 import functools
-import os
 from dataclasses import dataclass
 from typing import TYPE_CHECKING, Any, List, NamedTuple, Optional, Sequence, Tuple
 
@@ -223,8 +213,8 @@ def build_schedules(keys: Sequence[jax.Array], n_labeled: int,
     of ``(batches, bs_u)``: MT19937 hands out its 32-bit words with no
     buffering between calls, so that is the per-batch draws' sequence.
 
-    Keys and indices are materialized up front so the scan path and the
-    Python path consume bit-identical randomness. ``epochs == 0`` gives an
+    Keys and indices are materialized up front, so any consumer of the
+    schedule sees bit-identical randomness. ``epochs == 0`` gives an
     empty session; ``n_unlabeled == 0`` (a full-overlap party with an empty
     private pool) yields zero-width unlabeled batches, with no draw; the
     masked loss path keeps them at exactly zero contribution. Each call
@@ -270,55 +260,13 @@ def build_schedule(key: jax.Array, n_labeled: int, n_unlabeled: int,
     return Schedule(*(a[0] for a in sched))
 
 
-# ------------------------------------------------------- fallback: Python loop
 def _optimizer_key(hp: SSLHParams) -> tuple:
     """The hp fields the step math closes over (epochs/batch sizes only
     shape the schedule, which travels as arguments)."""
     return (hp.learning_rate, hp.momentum, hp.grad_clip)
 
 
-def train_party_ssl(key: jax.Array, task: PartyTask, hp: SSLHParams
-                    ) -> Tuple[PartyParams, dict]:
-    """One party's SSL session as a Python loop over the cached jitted step;
-    each step it dispatches counts in ``obs``'s ``ssl_host_steps``."""
-    tx = make_ssl_optimizer(hp)
-    step = sessions.cached_session(
-        "ssl",
-        ("step", sessions.model_key(task.extractor),
-         sessions.model_key(task.head), task.ssl_cfg, _optimizer_key(hp)),
-        lambda: jax.jit(make_ssl_step_fn(task.extractor, task.head,
-                                         task.ssl_cfg, tx),
-                        compiler_options=ssl_compiler_options()))
-    n_l = task.x_labeled.shape[0]
-    with obs.span("ssl.schedule", entries=1, steps=schedule_steps(n_l, hp)):
-        sched = build_schedule(key, n_l, task.x_unlabeled.shape[0], hp)
-    params, opt_state = task.params, tx.init(task.params)
-    idx_l = np.asarray(sched.idx_labeled)
-    idx_u = np.asarray(sched.idx_unlabeled)
-    m_l, m_u = task.labeled_mask, task.unlabeled_mask
-    sv = None if task.step_valid is None else np.asarray(task.step_valid)
-    metrics: dict = {}
-    obs.count("ssl_host_steps", idx_l.shape[0])
-    with obs.span("ssl.session"):
-        for i in range(idx_l.shape[0]):
-            # an invalid step still COMPUTES (so the recorded metrics match
-            # the vmapped session's frozen-carry step exactly) but never
-            # commits: params and optimizer state freeze together — no
-            # momentum coast
-            new_params, new_opt, m = step(
-                params, opt_state, task.feature_mean, sched.step_keys[i],
-                task.x_labeled[idx_l[i]], task.y_pseudo[idx_l[i]],
-                task.x_unlabeled[idx_u[i]],
-                None if m_l is None else m_l[idx_l[i]],
-                None if m_u is None else m_u[idx_u[i]])
-            if sv is None or sv[i] > 0:
-                params, opt_state = new_params, new_opt
-            metrics = m
-    with obs.span("ssl.readback"):
-        return params, {k: float(v) for k, v in metrics.items()}
-
-
-# ------------------------------------------------- fast path: vmap over clients
+# ---------------------------------------------- one session: vmap over clients
 def _stack(trees):
     return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
 
@@ -330,7 +278,7 @@ def _unstack(tree, k: int):
 def _apply_fns_match(a: Model, b: Model) -> bool:
     """True when two Models provably share forward semantics: the same
     function object, or the same factory code with equal captured closure
-    values. The vmap fast path trains every party with party 0's apply fn,
+    values. A stacked session trains every party with party 0's apply fn,
     so shape equality alone is not enough — two architectures can share
     param shapes yet compute different functions."""
     fa, fb = a.apply, b.apply
@@ -386,13 +334,13 @@ def tasks_are_homogeneous(tasks: Sequence[PartyTask]) -> bool:
 def parties_are_homogeneous(extractors: Sequence[Model],
                             ssl_cfgs: Sequence["SSLConfig"],
                             feature_shapes: Sequence[tuple]) -> bool:
-    """Spec-level equivalent of :func:`tasks_are_homogeneous`: the vmap
-    fast path's precondition evaluated *before* any ``PartyTask`` exists —
-    from a scenario's extractor stack, SSL configs, and per-party aligned
-    feature shapes. Equal data shapes alone are NOT sufficient (a model-zoo
-    scenario can have equal dims but distinct forward functions, which
-    legitimately takes the Python fallback); the apply-fn identity check is
-    what the engine actually dispatches on."""
+    """Spec-level equivalent of :func:`tasks_are_homogeneous`: whether all
+    parties share one SSL session, evaluated *before* any ``PartyTask``
+    exists — from a scenario's extractor stack, SSL configs, and per-party
+    aligned feature shapes. Equal data shapes alone are NOT sufficient (a
+    model-zoo scenario can have equal dims but distinct forward functions,
+    which train as one session per signature); the apply-fn identity check
+    is what the engine actually groups on."""
     e0 = extractors[0]
     if any(not _apply_fns_match(e, e0) for e in extractors[1:]):
         return False
@@ -534,7 +482,7 @@ def train_task_groups(keys: Sequence[jax.Array], tasks: Sequence[PartyTask],
     per group of :func:`ssl_task_groups` (a group of one runs the same
     scanned session at width 1); results come back in task order, each
     task trained with its own key, so every entry consumes the schedule
-    and PRNG stream it would on the per-party loop."""
+    and PRNG stream a session of its own would."""
     params: List[Any] = [None] * len(tasks)
     metrics: List[Any] = [None] * len(tasks)
     for idx in ssl_task_groups(tasks):
@@ -549,46 +497,21 @@ def train_task_groups(keys: Sequence[jax.Array], tasks: Sequence[PartyTask],
     return params, metrics
 
 
-# ---------------------------------------------------------------- dispatcher
+# ---------------------------------------------------------------- entry point
 def train_clients_ssl(key: jax.Array, tasks: Sequence[PartyTask],
                       hp: SSLHParams, mode: str = "auto", mesh=None
                       ) -> Tuple[List[PartyParams], List[dict], bool]:
-    """Run every party's local-SSL session; returns (params, metrics, vmapped).
+    """Run every party's local-SSL session; returns (params, metrics, True).
 
-    mode: "auto" (one scanned session per group of ``ssl_task_groups`` —
-    a single one for a homogeneous zoo), "vmap" (require one session over
-    all parties; raises on heterogeneous tasks), or "python" (force the
-    per-client loop, the parity oracle). Per-party keys are split
-    identically for every path, so they agree numerically to ~1e-5.
-    ``mesh`` (optional, DESIGN.md §14) shards each session's stacked
-    client axis across devices; the loop ignores it.
+    :func:`train_task_groups` over per-party keys split from ``key``: one
+    scanned session per group of ``ssl_task_groups``. ``mesh`` (optional,
+    DESIGN.md §14) shards each session's stacked client axis across
+    devices.
     """
-    if mode not in ("auto", "vmap", "python"):
+    # ``mode`` and the constant third value survive only for the benchmark
+    # tests (tests/bench), which pass mode="vmap" and unpack three values
+    if mode not in ("auto", "vmap"):
         raise ValueError(f"unknown engine mode {mode!r}")
-    keys = list(jax.random.split(key, len(tasks)))
-    homogeneous = tasks_are_homogeneous(tasks)
-    if mode == "auto":
-        # CI matrix knob: REPRO_ENGINE_MODE=python forces the fallback loop;
-        # =vmap prefers the fast path whenever the tasks allow it (without
-        # the hard failure an explicit mode="vmap" argument carries), so one
-        # env var exercises either engine path across the whole suite.
-        env = os.environ.get("REPRO_ENGINE_MODE", "")
-        if env == "python":
-            mode = "python"
-        elif env in ("vmap", "scan") and homogeneous:
-            mode = "vmap"
-    if mode == "vmap" and not homogeneous:
-        raise ValueError("engine mode 'vmap' requires homogeneous party "
-                         "tasks (same param/data shapes and SSLConfig); "
-                         "use mode='auto' or 'python'")
-    # explicit "vmap" always honors the request (even K=1); "auto" only
-    # pays the stacked-program trace when there is >1 party to batch
-    if mode == "vmap" or (mode == "auto" and len(tasks) > 1):
-        params, metrics = train_task_groups(keys, tasks, hp, mesh=mesh)
-        return params, metrics, True
-    params_list, metrics_list = [], []
-    for kk, t in zip(keys, tasks):
-        p, m = train_party_ssl(kk, t, hp)
-        params_list.append(p)
-        metrics_list.append(m)
-    return params_list, metrics_list, False
+    params, metrics = train_task_groups(
+        list(jax.random.split(key, len(tasks))), tasks, hp, mesh=mesh)
+    return params, metrics, True

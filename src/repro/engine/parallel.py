@@ -28,9 +28,9 @@ Design rules (mirroring every previous fold):
   to the single-device fold.
 * **Steering.** The mesh arrives via ``ProtocolConfig.mesh`` /
   ``IterativeConfig.mesh`` (``None`` | device count | ``jax.sharding.Mesh``)
-  or the env knob ``REPRO_DEVICE_COUNT`` — the device-axis analogue of
-  ``REPRO_ENGINE_MODE``. Results record ``diagnostics["device_fold"]``
-  alongside ``seed_fold`` / ``scenario_fold``.
+  or the env knob ``REPRO_DEVICE_COUNT``. Results record
+  ``diagnostics["device_fold"]`` alongside ``seed_fold`` /
+  ``scenario_fold``.
 """
 from __future__ import annotations
 

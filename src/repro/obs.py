@@ -29,8 +29,9 @@ another and never overlap.
 ``counters()`` gives process-wide integers for an operator who runs without
 the profiler: ``compiles`` (every backend compilation, a program loaded from
 the persistent compilation cache included), ``persistent_cache_hits`` (the
-loads among them), ``ssl_host_steps`` (SSL steps the per-party host loop
-dispatched one by one; 0 while every session runs scanned),
+loads among them), ``ssl_host_steps`` (always 0: nothing in the program
+dispatches SSL steps from the host, every session runs scanned; kept
+because the benchmark's metric of that name reads it),
 ``ssl_schedule_draws`` (host generator calls the SSL schedule builds made:
 2 per entry and epoch, 1 with an empty unlabeled pool) and the
 compiled-session cache's hits and misses (``engine.sessions``).
@@ -57,7 +58,7 @@ _CACHE_HIT = "/jax/compilation_cache/cache_hits"
 _COUNTS = {
     "compiles": 0,
     "persistent_cache_hits": 0,
-    "ssl_host_steps": 0,
+    "ssl_host_steps": 0,  # never counted: no SSL step runs from the host
     "ssl_schedule_draws": 0,
 }
 _NULL = contextlib.nullcontext()

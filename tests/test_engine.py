@@ -1,12 +1,13 @@
 """Engine-layer tests: the vmapped jitted multi-client path must be
-numerically equivalent to the per-client Python loop, record identical
-communication, and be the one implementation both the protocol and the
-multi-pod schedule train with."""
+numerically equivalent to the per-client host loop (``engine_oracles``),
+record identical communication, and be the one implementation both the
+protocol and the multi-pod schedule train with."""
 import jax
 import jax.numpy as jnp
 import pytest
+from engine_oracles import ssl_loop_seeds
 
-from repro import engine
+from repro import engine, obs
 from repro.core import ProtocolConfig, SSLConfig, run_one_shot
 from repro.core.client import make_client, ssl_task_for
 from repro.data import make_tabular_credit, make_vfl_partition
@@ -45,17 +46,15 @@ HP = engine.SSLHParams(epochs=2, batch_size=32)
 
 
 def test_vmap_equivalent_to_python_loop(homo_split):
-    """The tentpole invariant: vmap-over-clients scan == per-client Python
+    """The tentpole invariant: vmap-over-clients scan == per-client host
     loop, at atol 1e-5 on every parameter leaf."""
     key = jax.random.PRNGKey(7)
     clients = _clients(jax.random.PRNGKey(1), homo_split, [0, 1])
     tasks = _tasks(jax.random.PRNGKey(2), homo_split, clients)
 
-    p_vmap, m_vmap, vmapped = engine.train_clients_ssl(key, tasks, HP,
-                                                       mode="vmap")
-    p_py, m_py, vmapped_py = engine.train_clients_ssl(key, tasks, HP,
-                                                      mode="python")
-    assert vmapped and not vmapped_py
+    p_vmap, m_vmap, vmapped = engine.train_clients_ssl(key, tasks, HP)
+    (p_py,), (m_py,) = ssl_loop_seeds([key], [tasks], HP)
+    assert vmapped
     for pv, pp in zip(p_vmap, p_py):
         for lv, lp in zip(jax.tree_util.tree_leaves(pv),
                           jax.tree_util.tree_leaves(pp)):
@@ -66,19 +65,15 @@ def test_vmap_equivalent_to_python_loop(homo_split):
             assert abs(mv[name] - mp[name]) < 1e-4, (name, mv[name], mp[name])
 
 
-def test_auto_dispatch(homo_split, monkeypatch):
-    """auto → one vmapped session on homogeneous zoos, one scanned session
-    per party signature on heterogeneous ones; explicit vmap still refuses
-    a heterogeneous set.
-
-    Pins the DEFAULT dispatch, so the CI matrix's REPRO_ENGINE_MODE
-    override (which deliberately re-steers "auto") is stripped here."""
-    monkeypatch.delenv("REPRO_ENGINE_MODE", raising=False)
+def test_auto_dispatch(homo_split):
+    """A homogeneous zoo is one group (one scanned session), a
+    heterogeneous one is one group per party signature; ``mode`` takes
+    only "auto" and "vmap"."""
     clients = _clients(jax.random.PRNGKey(1), homo_split, [0, 1])
     tasks = _tasks(jax.random.PRNGKey(2), homo_split, clients)
     assert engine.tasks_are_homogeneous(tasks)
-    _, _, vmapped = engine.train_clients_ssl(jax.random.PRNGKey(3), tasks, HP,
-                                             mode="auto")
+    assert engine.ssl_task_groups(tasks) == [[0, 1]]
+    _, _, vmapped = engine.train_clients_ssl(jax.random.PRNGKey(3), tasks, HP)
     assert vmapped
 
     x, y = make_tabular_credit(jax.random.PRNGKey(0), 700)
@@ -89,26 +84,34 @@ def test_auto_dispatch(homo_split, monkeypatch):
     assert not engine.tasks_are_homogeneous(h_tasks)
     assert engine.ssl_task_groups(h_tasks) == [[0], [1]]
     _, _, vmapped = engine.train_clients_ssl(jax.random.PRNGKey(3), h_tasks,
-                                             HP, mode="auto")
+                                             HP, mode="vmap")
     assert vmapped
     with pytest.raises(ValueError):
         engine.train_clients_ssl(jax.random.PRNGKey(3), h_tasks, HP,
-                                 mode="vmap")
+                                 mode="python")
 
 
-def test_vmap_mode_honored_for_single_party(homo_split, monkeypatch):
-    """Explicit mode='vmap' must run the fast path even with K=1 (auto may
-    still prefer the plain loop there). Default-dispatch test: the CI
-    matrix's REPRO_ENGINE_MODE override is stripped."""
-    monkeypatch.delenv("REPRO_ENGINE_MODE", raising=False)
+def test_vmap_mode_honored_for_single_party(homo_split):
+    """K = 1 runs one width-1 scanned session, dispatches no SSL step from
+    the host, and matches the host loop at atol 1e-5."""
+    from repro.engine import sessions
+
     clients = _clients(jax.random.PRNGKey(1), homo_split, [0, 1])[:1]
     tasks = _tasks(jax.random.PRNGKey(2), homo_split, clients)[:1]
-    _, _, vmapped = engine.train_clients_ssl(jax.random.PRNGKey(3), tasks, HP,
-                                             mode="vmap")
+    key = jax.random.PRNGKey(3)
+    assert engine.ssl_task_groups(tasks) == [[0]]
+    steps0 = obs.counters()["ssl_host_steps"]
+    before = sessions.session_cache_stats("ssl")
+    params, _, vmapped = engine.train_clients_ssl(key, tasks, HP)
+    after = sessions.session_cache_stats("ssl")
     assert vmapped
-    _, _, vmapped = engine.train_clients_ssl(jax.random.PRNGKey(3), tasks, HP,
-                                             mode="auto")
-    assert not vmapped
+    assert obs.counters()["ssl_host_steps"] == steps0
+    assert (after["hits"] + after["misses"]
+            == before["hits"] + before["misses"] + 1)
+    (want,), _ = ssl_loop_seeds([key], [tasks], HP)
+    for a, b in zip(jax.tree_util.tree_leaves(params),
+                    jax.tree_util.tree_leaves(want)):
+        assert jnp.allclose(a, b, atol=1e-5), float(jnp.max(jnp.abs(a - b)))
 
 
 def test_homogeneity_checks_forward_fn(homo_split):
@@ -135,32 +138,33 @@ def test_homogeneity_checks_forward_fn(homo_split):
 
 
 def test_few_shot_with_vmap_mode(homo_split):
-    """engine_mode='vmap' must survive the whole few-shot run ON the fast
-    path: phase ⑤''s masked fixed-shape sessions stack at any ragged
-    per-party gate counts (DESIGN.md §9) — no downgrade, no raise."""
+    """A default-config few-shot run trains every SSL session scanned,
+    phase ⑤''s masked fixed-shape sessions at any ragged per-party gate
+    counts included (DESIGN.md §9): no SSL step is dispatched from the
+    host."""
     from repro.core import run_few_shot
 
     ext = [make_mlp_extractor(rep_dim=8, hidden=(16,)) for _ in range(2)]
     ssl = [SSLConfig(modality="tabular")] * 2
-    cfg = ProtocolConfig(client_epochs=2, server_epochs=3, engine_mode="vmap")
+    cfg = ProtocolConfig(client_epochs=2, server_epochs=3)
+    steps0 = obs.counters()["ssl_host_steps"]
     res = run_few_shot(jax.random.PRNGKey(1), homo_split, ext, ssl, cfg)
-    assert res.diagnostics["engine_path"] == "vmap"
+    assert obs.counters()["ssl_host_steps"] == steps0
     assert res.ledger.comm_times() == 5
     assert res.metric > 0.5
 
 
-def test_protocol_ledger_identical_across_paths(homo_split):
-    """run_one_shot through either engine path: identical CommLedger bytes,
-    the paper's 3 comm times, and matching metrics."""
+def test_protocol_ledger_identical_across_paths(homo_split, monkeypatch):
+    """run_one_shot on the engine and on the host loop: identical CommLedger
+    bytes, the paper's 3 comm times, and matching metrics."""
     ext = [make_mlp_extractor(rep_dim=8, hidden=(16,)) for _ in range(2)]
     ssl = [SSLConfig(modality="tabular")] * 2
-    results = {}
-    for mode in ("vmap", "python"):
-        cfg = ProtocolConfig(client_epochs=2, server_epochs=3,
-                             engine_mode=mode)
-        results[mode] = run_one_shot(jax.random.PRNGKey(1), homo_split, ext,
+    cfg = ProtocolConfig(client_epochs=2, server_epochs=3)
+    results = {"vmap": run_one_shot(jax.random.PRNGKey(1), homo_split, ext,
+                                    ssl, cfg)}
+    monkeypatch.setattr(engine, "train_clients_ssl_seeds", ssl_loop_seeds)
+    results["python"] = run_one_shot(jax.random.PRNGKey(1), homo_split, ext,
                                      ssl, cfg)
-        assert results[mode].diagnostics["engine_path"] == mode
     v, p = results["vmap"].ledger, results["python"].ledger
     assert v.total_bytes() == p.total_bytes()
     assert v.comm_times() == p.comm_times() == 3

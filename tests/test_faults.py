@@ -356,20 +356,12 @@ def fault_baseline_path(tmp_path):
     return str(p)
 
 
-@pytest.fixture
-def no_engine_env(monkeypatch):
-    # fold/engine-path discipline is the vmap leg's concern
-    # (test_frontier_gate.py) — these tests isolate the fault floors
-    monkeypatch.delenv("REPRO_ENGINE_MODE", raising=False)
-
-
-def test_fault_gate_green(fault_baseline_path, no_engine_env):
+def test_fault_gate_green(fault_baseline_path):
     assert frontier.check_gate(_fault_green_rows(), fault_baseline_path,
                                expect_faults=True) == []
 
 
-def test_zero_fault_rows_violate_only_in_full_sweeps(fault_baseline_path,
-                                                     no_engine_env):
+def test_zero_fault_rows_violate_only_in_full_sweeps(fault_baseline_path):
     plain = [{k: v for k, v in r.items()
               if k not in ("fault_kind", "parties_survived", "fault_stage",
                            "degraded_metric", "fault_retry_rounds",
@@ -383,7 +375,7 @@ def test_zero_fault_rows_violate_only_in_full_sweeps(fault_baseline_path,
                                expect_faults=False) == []
 
 
-def test_missing_family_member_violates(fault_baseline_path, no_engine_env):
+def test_missing_family_member_violates(fault_baseline_path):
     rows = [r for r in _fault_green_rows()
             if r["scenario"] != "fault/strag"]
     problems = frontier.check_gate(rows, fault_baseline_path,
@@ -391,7 +383,7 @@ def test_missing_family_member_violates(fault_baseline_path, no_engine_env):
     assert any("fault/strag" in p and "whole family" in p for p in problems)
 
 
-def test_dropout_survivor_count_violates(fault_baseline_path, no_engine_env):
+def test_dropout_survivor_count_violates(fault_baseline_path):
     rows = _fault_green_rows()
     for r in rows:
         if r["scenario"] == "fault/drop" and r["method"] == "one_shot":
@@ -410,8 +402,7 @@ def test_dropout_survivor_count_violates(fault_baseline_path, no_engine_env):
     assert any("straggler" in p and "expected 4" in p for p in problems)
 
 
-def test_iterative_dropout_without_retry_cost_violates(fault_baseline_path,
-                                                       no_engine_env):
+def test_iterative_dropout_without_retry_cost_violates(fault_baseline_path):
     rows = _fault_green_rows()
     for r in rows:
         if r["scenario"] == "fault/drop" and r["method"] == "iterative":
@@ -422,8 +413,7 @@ def test_iterative_dropout_without_retry_cost_violates(fault_baseline_path,
     assert any("no retry/timeout cost" in p for p in problems)
 
 
-def test_oneshot_degradation_floor_violates(fault_baseline_path,
-                                            no_engine_env):
+def test_oneshot_degradation_floor_violates(fault_baseline_path):
     rows = _fault_green_rows()
     for r in rows:
         if r["scenario"] == "fault/drop" and r["method"] == "one_shot":
@@ -435,7 +425,7 @@ def test_oneshot_degradation_floor_violates(fault_baseline_path,
 
 
 def test_missing_twin_and_missing_degraded_metric_violate(
-        fault_baseline_path, no_engine_env):
+        fault_baseline_path):
     rows = [r for r in _fault_green_rows()
             if not (r["scenario"] == "fault/none"
                     and r["method"] == "one_shot")]

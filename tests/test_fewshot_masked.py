@@ -1,10 +1,10 @@
 """Masked fixed-shape SSL sessions + the shared session cache (DESIGN.md §9).
 
-* vmap ≡ python parity for *masked* tasks at deliberately ragged per-party
-  valid-row counts (the few-shot ⑤' shape problem, isolated);
-* ``run_few_shot`` keeps the vmapped engine path end-to-end under
-  ``engine_mode="vmap"`` — no downgrade — with byte-identical ledgers
-  across modes;
+* scanned session ≡ per-party host loop (``engine_oracles``) for *masked*
+  tasks at deliberately ragged per-party valid-row counts (the few-shot ⑤'
+  shape problem, isolated);
+* ``run_few_shot`` on the engine and on the host loop record byte-identical
+  ledgers;
 * Eq. 9 gating is deterministic (every sample with p̂ > 0 is kept); the
   legacy Bernoulli subsampling sits behind ``fewshot_stochastic_gate``;
 * all-gated pools are represented as zero-valid unlabeled masks (no row in
@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from engine_oracles import ssl_loop_seeds
 
 from repro import engine
 from repro.core import (IterativeConfig, ProtocolConfig, SSLConfig,
@@ -76,16 +77,14 @@ def test_masked_tasks_are_homogeneous_at_ragged_counts(homo_split):
 
 
 def test_masked_vmap_equivalent_to_python_loop(homo_split):
-    """The tentpole invariant at ragged gate counts: masked fast path ==
-    per-client Python fallback at atol 1e-5 on every parameter leaf."""
+    """The tentpole invariant at ragged gate counts: masked scanned session
+    == per-client host loop at atol 1e-5 on every parameter leaf."""
     clients = _clients(jax.random.PRNGKey(1), homo_split)
     tasks = _masked_tasks(jax.random.PRNGKey(2), homo_split, clients, [3, 170])
     key = jax.random.PRNGKey(7)
-    p_vmap, m_vmap, vmapped = engine.train_clients_ssl(key, tasks, HP,
-                                                       mode="vmap")
-    p_py, m_py, vmapped_py = engine.train_clients_ssl(key, tasks, HP,
-                                                      mode="python")
-    assert vmapped and not vmapped_py
+    p_vmap, m_vmap, vmapped = engine.train_clients_ssl(key, tasks, HP)
+    (p_py,), (m_py,) = ssl_loop_seeds([key], [tasks], HP)
+    assert vmapped
     for pv, pp in zip(p_vmap, p_py):
         for lv, lp in zip(jax.tree_util.tree_leaves(pv),
                           jax.tree_util.tree_leaves(pp)):
@@ -139,17 +138,19 @@ def _fast(**kw):
     return ProtocolConfig(client_epochs=2, server_epochs=3, **kw)
 
 
-def test_few_shot_stays_on_vmap_path_with_ragged_gates(homo_split):
-    """engine_mode='vmap' survives the whole few-shot run: phase ⑤''s masked
-    sessions stack at any per-party gate counts — no downgrade, and the
-    ledger is byte-identical to the python path's."""
+def test_few_shot_stays_on_vmap_path_with_ragged_gates(homo_split,
+                                                       monkeypatch):
+    """At ragged per-party gate counts, the few-shot run on the engine
+    records a ledger byte-identical to the host loop's."""
     ext = [make_mlp_extractor(rep_dim=8, hidden=(16,)) for _ in range(2)]
     ssl = [SSLConfig(modality="tabular")] * 2
     results = {}
     for mode in ("vmap", "python"):
+        if mode == "python":
+            monkeypatch.setattr(engine, "train_clients_ssl_seeds",
+                                ssl_loop_seeds)
         res = run_few_shot(jax.random.PRNGKey(1), homo_split, ext, ssl,
-                           _fast(engine_mode=mode))
-        assert res.diagnostics["engine_path"] == mode
+                           _fast())
         assert res.ledger.comm_times() == 5
         results[mode] = res
     # ragged gates actually exercised (else the test proves nothing)
@@ -221,7 +222,7 @@ def test_all_gated_pool_trains_without_leak(homo_split):
     for t in tasks:
         assert float(jnp.sum(t.unlabeled_mask)) == 0.0
     params, metrics, vmapped = engine.train_clients_ssl(
-        jax.random.PRNGKey(3), tasks, HP, mode="vmap")
+        jax.random.PRNGKey(3), tasks, HP)
     assert vmapped
     for m in metrics:
         assert m["l_u"] == 0.0

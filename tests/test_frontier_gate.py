@@ -4,11 +4,12 @@ only when bench CI happens to run:
 
 * a fully consistent blob stays green;
 * missing few-shot rows violate (an unmeasured margin must not pass);
-* ``seed_fold`` / ``scenario_fold`` mismatches violate under the vmap CI
-  matrix leg (the folds must actually have run);
-* engine-path, bytes-invariance, bytes-regression, and margin floors
-  violate exactly when they should — and the dominance checks apply only
-  to baseline-listed scenarios (the full smoke catalog's unlisted rows get
+* ``seed_fold`` / ``scenario_fold`` mismatches violate (the folds must
+  actually have run), and so does a ``device_fold`` mismatch under
+  ``--devices``;
+* bytes-invariance, bytes-regression, and margin floors violate exactly
+  when they should — and the dominance checks apply only to
+  baseline-listed scenarios (the full smoke catalog's unlisted rows get
   invariance + fold discipline only).
 """
 import json
@@ -36,8 +37,6 @@ _METRIC = {"one_shot": 0.92, "few_shot": 0.93,
            "iterative": 0.80, "fedcvt": 0.82}
 _BYTES = {"one_shot": 12288, "few_shot": 20480,
           "iterative": 12288 * 200, "fedcvt": 12288 * 220}
-_PATH = {"one_shot": "vmap", "few_shot": "vmap",
-         "iterative": "scan", "fedcvt": "scan"}
 
 
 def _row(method, seed, scenario="hard/overlap-32", **over):
@@ -49,7 +48,6 @@ def _row(method, seed, scenario="hard/overlap-32", **over):
         "metric": _METRIC[method],
         "comm_bytes": _BYTES[method],
         "comm_times": 3,
-        "engine_path": _PATH[method],
         "seed_fold": len(SEEDS),
         "scenario_fold": 1,
         "group_size": 1,
@@ -74,30 +72,25 @@ def baseline_path(tmp_path):
     return str(p)
 
 
-@pytest.fixture
-def vmap_env(monkeypatch):
-    monkeypatch.setenv("REPRO_ENGINE_MODE", "vmap")
-
-
-def test_green_blob_passes(baseline_path, vmap_env):
+def test_green_blob_passes(baseline_path):
     assert frontier.check_gate(_green_rows(), baseline_path) == []
 
 
-def test_aggregate_rows_are_ignored(baseline_path, vmap_env):
+def test_aggregate_rows_are_ignored(baseline_path):
     rows = _green_rows()
     # a degenerate aggregate row must not feed the per-seed checks
     rows.append(_row("one_shot", "aggregate", aggregate=True,
-                     engine_path="python", seed_fold=1, scenario_fold=0))
+                     seed_fold=1, scenario_fold=0))
     assert frontier.check_gate(rows, baseline_path) == []
 
 
-def test_missing_few_shot_rows_violate(baseline_path, vmap_env):
+def test_missing_few_shot_rows_violate(baseline_path):
     rows = [r for r in _green_rows() if r["method"] != "few_shot"]
     problems = frontier.check_gate(rows, baseline_path)
     assert any("no few_shot rows" in p for p in problems)
 
 
-def test_seed_fold_mismatch_violates(baseline_path, vmap_env):
+def test_seed_fold_mismatch_violates(baseline_path):
     rows = _green_rows()
     rows[0] = dict(rows[0], seed_fold=1)
     problems = frontier.check_gate(rows, baseline_path)
@@ -105,7 +98,7 @@ def test_seed_fold_mismatch_violates(baseline_path, vmap_env):
                for p in problems)
 
 
-def test_scenario_fold_mismatch_violates(baseline_path, vmap_env):
+def test_scenario_fold_mismatch_violates(baseline_path):
     """A row recorded against a size-C group must have folded all C
     scenarios — the grouped sweep silently degrading to the per-scenario
     loop is exactly what this assert exists to catch."""
@@ -117,31 +110,27 @@ def test_scenario_fold_mismatch_violates(baseline_path, vmap_env):
                for p in problems)
 
 
-def test_fold_checks_only_under_vmap_matrix_leg(baseline_path, monkeypatch):
-    """Outside the forced-vmap CI leg the fold/engine-path discipline is
-    not asserted (the python leg legitimately loops) — the dominance
-    checks still are."""
-    monkeypatch.delenv("REPRO_ENGINE_MODE", raising=False)
-    rows = _green_rows(seed_fold=1, scenario_fold=1, group_size=3,
-                       engine_path="python")
-    assert frontier.check_gate(rows, baseline_path) == []
-
-
-def test_engine_path_violations(baseline_path, vmap_env):
-    rows = _green_rows()
-    rows[0] = dict(rows[0], engine_path="python")          # one_shot, vmap-able
-    rows[4] = dict(rows[4], engine_path="python")          # iterative
+def test_fold_checks_only_under_vmap_matrix_leg(baseline_path):
+    """The fold checks apply with no environment variable set: a sweep
+    whose rows ran neither the seed nor the scenario fold violates on
+    every row, twice."""
+    rows = _green_rows(seed_fold=1, scenario_fold=1, group_size=3)
     problems = frontier.check_gate(rows, baseline_path)
-    assert sum("engine_path='python'" in p for p in problems) == 2
-    # heterogeneous party zoos are exempt from the protocol-path check
-    rows = _green_rows(vmap_eligible=False)
-    for r in rows:
-        if r["method"] in ("one_shot", "few_shot"):
-            r["engine_path"] = "python"
-    assert frontier.check_gate(rows, baseline_path) == []
+    assert sum("seed_fold=1" in p for p in problems) == len(rows)
+    assert sum("scenario_fold=1" in p for p in problems) == len(rows)
 
 
-def test_bytes_invariance_and_regression(baseline_path, vmap_env):
+def test_engine_path_violations(baseline_path):
+    """Under ``--devices N`` every row must record ``device_fold == N``."""
+    rows = _green_rows(device_fold=2)
+    assert frontier.check_gate(rows, baseline_path, devices=2) == []
+    rows[0] = dict(rows[0], device_fold=1)                 # one_shot
+    rows[4] = dict(rows[4], device_fold=1)                 # iterative
+    problems = frontier.check_gate(rows, baseline_path, devices=2)
+    assert sum("device_fold=1" in p for p in problems) == 2
+
+
+def test_bytes_invariance_and_regression(baseline_path):
     rows = _green_rows()
     rows[1] = dict(rows[1], comm_bytes=_BYTES["one_shot"] + 4)
     problems = frontier.check_gate(rows, baseline_path)
@@ -154,7 +143,7 @@ def test_bytes_invariance_and_regression(baseline_path, vmap_env):
     assert any("regressed" in p for p in problems)
 
 
-def test_margin_floors_violate(baseline_path, vmap_env):
+def test_margin_floors_violate(baseline_path):
     rows = _green_rows()
     for r in rows:
         if r["method"] == "one_shot":
@@ -169,7 +158,7 @@ def test_margin_floors_violate(baseline_path, vmap_env):
     assert any("few-shot worst-seed margin" in p for p in problems)
 
 
-def test_bytes_ratio_violates(baseline_path, vmap_env):
+def test_bytes_ratio_violates(baseline_path):
     rows = _green_rows()
     for r in rows:
         if r["method"] == "iterative":
@@ -179,7 +168,7 @@ def test_bytes_ratio_violates(baseline_path, vmap_env):
 
 
 def test_dominance_checks_scoped_to_baseline_listed_scenarios(
-        baseline_path, vmap_env):
+        baseline_path):
     """An unlisted low-overlap scenario (e.g. the smoke catalog's image
     rows, whose iteration budgets make no 100x claim) gets NO dominance
     checks — but keeps seed-invariance and fold discipline."""

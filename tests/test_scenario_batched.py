@@ -85,8 +85,8 @@ def _assert_grid_matches_loop(folded, loop):
         for res, ref in zip(scen_folded, scen_loop):
             assert abs(float(res.metric) - float(ref.metric)) < 1e-5, \
                 (float(res.metric), float(ref.metric))
-            assert res.diagnostics["engine_path"] == \
-                ref.diagnostics["engine_path"]
+            assert res.diagnostics["device_fold"] == \
+                ref.diagnostics["device_fold"]
             _assert_ledgers_equal(res.ledger, ref.ledger)
             for cb, cs in zip(res.clients, ref.clients):
                 for lb, ls in zip(jax.tree_util.tree_leaves(cb.params),
@@ -128,16 +128,13 @@ def test_scenario_fold_matches_per_scenario_loop_few_shot(grid_splits):
 
 def test_scenario_fold_matches_per_scenario_loop_iterative(grid_splits):
     """The §11 scan fold rides the same anonymous axis: C·S stacked
-    whole-session carries == the per-scenario loop, on whichever engine
-    path the CI matrix leg steers (loop parity already asserts folded
-    path == loop path per entry)."""
+    whole-session carries == the per-scenario loop."""
     icfg = IterativeConfig(iterations=10)
     folded = _run_grid(run_vanilla, grid_splits, icfg)
     loop = _run_loop(run_vanilla, grid_splits, icfg)
     _assert_grid_matches_loop(folded, loop)
     for scen in folded:
         for r in scen:
-            assert r.diagnostics["engine_path"] in ("scan", "python")
             assert r.diagnostics["scenario_fold"] == len(grid_splits)
 
 

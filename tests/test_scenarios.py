@@ -5,7 +5,8 @@
 * every registered scenario builds and completes a one-shot session (tiny
   budgets, smoke sizes) with the paper's 3 comm times;
 * the iterative baselines' ledgers count exactly one up + one down transfer
-  per round in BOTH engine execution modes, with byte-identical totals;
+  per round, on the engine's scan fold and on the per-step host loop
+  (``engine_oracles``) alike, with byte-identical totals;
 * the engine's iterative session cache re-serves compiled programs across
   calls (the no-recompile contract of DESIGN.md §8).
 """
@@ -13,11 +14,19 @@ import dataclasses
 
 import jax
 import pytest
+from engine_oracles import iterative_loop_seeds
 
 from repro import scenarios
 from repro.core import (IterativeConfig, ProtocolConfig, run_one_shot,
                         run_vanilla)
-from repro.engine import iterative
+from repro.engine import iterative, sessions
+
+
+def _on_path(mode, monkeypatch):
+    """Steer the iterative sessions onto the host loop for "python"."""
+    if mode == "python":
+        monkeypatch.setattr(iterative, "run_iterative_session_seeds",
+                            iterative_loop_seeds)
 
 
 def test_registry_roundtrip():
@@ -75,11 +84,11 @@ def test_every_scenario_builds_and_runs_one_shot(name):
 
 
 @pytest.mark.parametrize("mode", ["scan", "python"])
-def test_iterative_ledger_counts_one_up_one_down_per_round(mode):
+def test_iterative_ledger_counts_one_up_one_down_per_round(mode, monkeypatch):
+    _on_path(mode, monkeypatch)
     bundle = scenarios.build("credit/overlap-64", seed=0, smoke=True)
     res = run_vanilla(jax.random.PRNGKey(1), bundle.split, bundle.extractors,
-                      bundle.ssl_cfgs,
-                      IterativeConfig(iterations=25, engine_mode=mode))
+                      bundle.ssl_cfgs, IterativeConfig(iterations=25))
     # 2 rounds (reps up, grads down) per iteration per client
     assert res.ledger.comm_times() == 2 * 25
     ups = [e for e in res.ledger.events if e.direction == "up"]
@@ -87,16 +96,16 @@ def test_iterative_ledger_counts_one_up_one_down_per_round(mode):
     assert len(ups) == len(downs) == 25 * 2      # per client per iteration
     bs, rep = 32, bundle.spec.rep_dim
     assert res.ledger.total_bytes() == 25 * 2 * 2 * bs * rep * 4
-    assert res.diagnostics["engine_path"] == mode
 
 
-def test_iterative_engine_modes_agree():
+def test_iterative_engine_modes_agree(monkeypatch):
     bundle = scenarios.build("credit/overlap-64", seed=0, smoke=True)
     runs = {}
     for mode in ("scan", "python"):
+        _on_path(mode, monkeypatch)
         res = run_vanilla(jax.random.PRNGKey(2), bundle.split,
                           bundle.extractors, bundle.ssl_cfgs,
-                          IterativeConfig(iterations=30, engine_mode=mode))
+                          IterativeConfig(iterations=30))
         runs[mode] = res
     assert abs(runs["scan"].metric - runs["python"].metric) < 1e-4
     assert (runs["scan"].ledger.total_bytes()
@@ -104,17 +113,17 @@ def test_iterative_engine_modes_agree():
 
 
 def test_iterative_session_cache_reuses_compiled_program():
-    iterative.clear_session_cache()
+    sessions.clear_session_cache()
     bundle = scenarios.build("hard/overlap-32", seed=0, smoke=True)
-    cfg = IterativeConfig(iterations=10, engine_mode="scan")
+    cfg = IterativeConfig(iterations=10)
     for seed in (0, 1):
         run_vanilla(jax.random.PRNGKey(seed), bundle.split, bundle.extractors,
                     bundle.ssl_cfgs, cfg)
-    stats = iterative.session_cache_stats()
+    stats = sessions.session_cache_stats("iterative")
     assert stats["misses"] == 1                  # compiled exactly once
     assert stats["hits"] == 1                    # second session re-served
     # fresh-but-equivalent extractors (same factory arguments) also hit
     b2 = scenarios.build("hard/overlap-32", seed=2, smoke=True)
     run_vanilla(jax.random.PRNGKey(3), b2.split, b2.extractors, b2.ssl_cfgs,
                 cfg)
-    assert iterative.session_cache_stats()["hits"] == 2
+    assert sessions.session_cache_stats("iterative")["hits"] == 2

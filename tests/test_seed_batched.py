@@ -144,8 +144,8 @@ def test_run_seeds_matches_single_seed_loop_iterative(runner, icfg, splits):
         assert abs(float(res.metric) - float(solo.metric)) < 1e-5, \
             (s, float(res.metric), float(solo.metric))
         _assert_ledgers_equal(res.ledger, solo.ledger)
-        assert res.diagnostics["engine_path"] == \
-            solo.diagnostics["engine_path"]
+        assert res.diagnostics["device_fold"] == \
+            solo.diagnostics["device_fold"]
         for cb, cs in zip(res.clients, solo.clients):
             for lb, ls in zip(jax.tree_util.tree_leaves(cb.params),
                               jax.tree_util.tree_leaves(cs.params)):

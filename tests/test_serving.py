@@ -299,7 +299,7 @@ def test_row_builders_validate_and_unify():
     class FakeResult:
         metric_name = "auc"
         metric = 0.9
-        diagnostics = {"engine_path": "vmap", "seed_fold": 2}
+        diagnostics = {"seed_fold": 2}
 
         class ledger:  # noqa: N801 — duck-typed CommLedger
             @staticmethod
@@ -312,9 +312,9 @@ def test_row_builders_validate_and_unify():
 
     trow = result_rows.training_row(FakeResult(), scenario="s", seed=0)
     assert trow["kind"] == "train" and trow["comm_bytes"] == 123
-    assert trow["engine_path"] == "vmap" and trow["scenario"] == "s"
+    assert trow["seed_fold"] == 2 and trow["scenario"] == "s"
     with pytest.raises(ValueError, match="collide"):
-        result_rows.training_row(FakeResult(), engine_path="python")
+        result_rows.training_row(FakeResult(), seed_fold=1)
 
 
 def test_serving_gate_consumes_typed_rows(tmp_path):

@@ -46,12 +46,8 @@ from repro.data import (make_tabular_credit,                  # noqa: E402
                         make_vfl_partition)
 from repro.models import make_mlp_extractor                   # noqa: E402
 
-# the module tests the FOLDED paths (only they have a stacked axis to
-# shard), so pin the engine modes rather than inherit the CI matrix knob —
-# under REPRO_ENGINE_MODE=python these would otherwise resolve to the
-# per-client/per-step loops, where mesh is (correctly) ignored
-_FAST = ProtocolConfig(client_epochs=2, server_epochs=3, engine_mode="vmap")
-_ITER = IterativeConfig(iterations=60, eval_every=30, engine_mode="scan")
+_FAST = ProtocolConfig(client_epochs=2, server_epochs=3)
+_ITER = IterativeConfig(iterations=60, eval_every=30)
 _SSL = [SSLConfig(modality="tabular")] * 2
 
 
@@ -156,16 +152,9 @@ def test_mesh_keyed_cache_discipline():
 
 def test_device_fold_diagnostic_pins():
     """``device_fold`` records the width the heavy stage actually folded:
-    the mesh size on the folded engine paths, 1 on the Python fallback
-    (where no stacked axis exists to shard)."""
+    the mesh size."""
     import dataclasses
     seeds = (0, 1)
     sharded = _run(run_vanilla, seeds, dataclasses.replace(_ITER, mesh=2))
     for r in sharded:
-        assert r.diagnostics["engine_path"] == "scan"
         assert r.diagnostics["device_fold"] == 2
-    python_cfg = dataclasses.replace(_ITER, mesh=2, engine_mode="python")
-    looped = _run(run_vanilla, seeds, python_cfg)
-    for r in looped:
-        assert r.diagnostics["engine_path"] == "python"
-        assert r.diagnostics["device_fold"] == 1

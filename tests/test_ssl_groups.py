@@ -1,8 +1,8 @@
 """The SSL group fold (DESIGN.md §10): party-heterogeneous tasks — here the
 (10, 13)-feature credit split — train as one scanned session per party
-signature instead of the per-step host loop, matching that loop
-(``mode="python"``, the parity oracle) entry for entry; a homogeneous set
-stays one session under the cache key it always had."""
+signature, matching the per-step host loop (``engine_oracles``, the
+parity oracle) entry for entry; a homogeneous set stays one session under
+the cache key it always had."""
 import dataclasses
 import glob
 import os
@@ -10,6 +10,7 @@ import os
 import jax
 import jax.numpy as jnp
 import pytest
+from engine_oracles import ssl_loop_seeds
 
 from repro import engine, obs
 from repro.core import ProtocolConfig, SSLConfig, run_few_shot, run_seeds
@@ -56,13 +57,6 @@ def _tasks(seed, split, masked=False):
     return tasks
 
 
-@pytest.fixture(autouse=True)
-def default_dispatch(monkeypatch):
-    """These tests pin the default dispatch: the CI matrix's override of
-    "auto" is stripped."""
-    monkeypatch.delenv("REPRO_ENGINE_MODE", raising=False)
-
-
 @pytest.fixture(scope="module")
 def hetero():
     return [_split(s, (10, 13)) for s in range(S)]
@@ -75,10 +69,8 @@ def test_grouped_fold_matches_the_python_loop(hetero, masked):
     assert not engine.tasks_are_homogeneous(flat)
     assert engine.ssl_task_groups(flat) == [[0, 2, 4], [1, 3, 5]]
     keys = [jax.random.PRNGKey(40 + s) for s in range(S)]
-    p_fold, m_fold, paths = engine.train_clients_ssl_seeds(keys, tasks, HP)
-    p_loop, m_loop, loop_paths = engine.train_clients_ssl_seeds(
-        keys, tasks, HP, mode="python")
-    assert paths == ["vmap"] * S and loop_paths == ["python"] * S
+    p_fold, m_fold = engine.train_clients_ssl_seeds(keys, tasks, HP)
+    p_loop, m_loop = ssl_loop_seeds(keys, tasks, HP)
     for s in range(S):
         for pf, pl, mf, ml in zip(p_fold[s], p_loop[s], m_fold[s], m_loop[s]):
             for a, b in zip(jax.tree_util.tree_leaves(pf),
@@ -94,25 +86,25 @@ def test_single_seed_heterogeneous_runs_width_one_sessions(hetero):
     tasks = _tasks(0, hetero[0])
     key = jax.random.PRNGKey(3)
     p_fold, _, vmapped = engine.train_clients_ssl(key, tasks, HP)
-    p_loop, _, looped = engine.train_clients_ssl(key, tasks, HP,
-                                                 mode="python")
-    assert vmapped and not looped
+    (p_loop,), _ = ssl_loop_seeds([key], [tasks], HP)
+    assert vmapped
     for a, b in zip(jax.tree_util.tree_leaves(p_fold),
                     jax.tree_util.tree_leaves(p_loop)):
         assert jnp.allclose(a, b, atol=1e-5)
 
 
-def test_ledgers_identical_across_paths(hetero):
+def test_ledgers_identical_across_paths(hetero, monkeypatch):
     cfg = ProtocolConfig(client_epochs=1, server_epochs=2, batch_size=16)
     keys = [jax.random.PRNGKey(60 + s) for s in range(S)]
-    runs = {}
-    for mode in ("auto", "python"):
-        runs[mode] = run_seeds(run_few_shot, keys, hetero,
-                               [_ext() for _ in range(S)], [_SSL] * S,
-                               dataclasses.replace(cfg, engine_mode=mode))
+
+    def run():
+        return run_seeds(run_few_shot, keys, hetero,
+                         [_ext() for _ in range(S)], [_SSL] * S, cfg)
+
+    runs = {"auto": run()}
+    monkeypatch.setattr(engine, "train_clients_ssl_seeds", ssl_loop_seeds)
+    runs["python"] = run()
     for fold, loop in zip(runs["auto"], runs["python"]):
-        assert fold.diagnostics["engine_path"] == "vmap"
-        assert loop.diagnostics["engine_path"] == "python"
         assert fold.ledger.events == loop.ledger.events
         assert fold.ledger.comm_times() == loop.ledger.comm_times() == 5
 
@@ -135,35 +127,34 @@ def test_homogeneous_set_is_one_group_under_its_old_cache_key():
 
 
 def test_host_step_counter(hetero):
+    """The fold dispatches no SSL step from the host, at S = 1 and S > 1."""
     tasks = [_tasks(s, sp) for s, sp in enumerate(hetero)]
     keys = [jax.random.PRNGKey(s) for s in range(S)]
     c0 = obs.counters()["ssl_host_steps"]
+    engine.train_clients_ssl_seeds(keys[:1], tasks[:1], HP)
+    assert obs.counters()["ssl_host_steps"] == c0
     engine.train_clients_ssl_seeds(keys, tasks, HP)
-    c1 = obs.counters()["ssl_host_steps"]
-    engine.train_clients_ssl_seeds(keys, tasks, HP, mode="python")
-    c2 = obs.counters()["ssl_host_steps"]
-    steps = sum(engine.schedule_steps(t.x_labeled.shape[0], HP)
-                for t in engine.flatten_seed_tasks(tasks))
-    assert c1 == c0
-    assert c2 - c1 == steps == 2 * S * HP.epochs * (48 // 16)
+    assert obs.counters()["ssl_host_steps"] == c0
 
 
 @pytest.mark.parametrize("empty_pool", [False, True])
 def test_schedule_draw_counter(hetero, empty_pool):
     """A grouped run's schedule builds make 2 generator calls per entry and
     epoch (the labeled shuffle and the unlabeled draw), 1 with no pool;
-    the per-party loop builds the same schedules and counts the same."""
+    the per-party host loop builds the same schedules and counts the
+    same."""
     tasks = [_tasks(s, sp) for s, sp in enumerate(hetero)]
     if empty_pool:
         tasks = [[dataclasses.replace(t, x_unlabeled=t.x_unlabeled[:0])
                   for t in ts] for ts in tasks]
     keys = [jax.random.PRNGKey(s) for s in range(S)]
     per_entry = HP.epochs * (1 if empty_pool else 2)
-    for mode in ("auto", "python"):
+    for name, train in (("fold", engine.train_clients_ssl_seeds),
+                        ("loop", ssl_loop_seeds)):
         before = obs.counters()["ssl_schedule_draws"]
-        engine.train_clients_ssl_seeds(keys, tasks, HP, mode=mode)
+        train(keys, tasks, HP)
         assert (obs.counters()["ssl_schedule_draws"] - before
-                == 2 * S * per_entry), mode
+                == 2 * S * per_entry), name
 
 
 def test_group_span_carries_its_attributes(hetero, tmp_path):
